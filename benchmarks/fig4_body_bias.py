@@ -28,4 +28,6 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     run()

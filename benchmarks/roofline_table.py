@@ -29,4 +29,6 @@ def run(results_dir: str = "results"):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     run()

@@ -9,6 +9,8 @@ sys.path.insert(0, os.path.join(
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     import table1_fpu_summary
     import table2_comparison

@@ -5,7 +5,9 @@ One ``ClusterRouter`` owns one ``BatchedServer`` (or ``ResilientServer``)
 replica per die of a ``ClusterSpec``, all sharing the same model, params,
 and injected clock (replicas over the same ``LM`` instance also share the
 warm jitted executables — the module-level compile cache in
-``repro.serve.engine`` is keyed on the model).
+``repro.serve.engine`` is keyed on the model).  With ``devices`` each die's
+replica runs on its own accelerator: its copy of the params is placed
+there, and the engine allocates its cache next to its params.
 
 Routing generalizes the single-die admission pipeline one level up:
 
@@ -42,7 +44,9 @@ bitwise-identical to driving that ``BatchedServer`` directly.
 from __future__ import annotations
 
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+import jax
 
 from repro.cluster.spec import ClusterSpec
 from repro.core.chip import ChipPolicy, ChipSpec
@@ -69,7 +73,9 @@ class ClusterRouter:
     replica construction (e.g. ``ResilientServer`` with a per-die fault
     injector); the default builds a ``BatchedServer`` with the shared
     keyword arguments.  ``slots`` may be an int (same on every die) or a
-    ``{die_name: int}`` mapping.
+    ``{die_name: int}`` mapping.  ``devices`` (one per die, in
+    ``cluster.chips`` order) puts each default-built replica on its own
+    device; ``None`` keeps every replica on the default device.
     """
 
     def __init__(self, model, params, cluster: ClusterSpec, *,
@@ -79,7 +85,15 @@ class ClusterRouter:
                      [str, ChipSpec, ChipPolicy], BatchedServer]] = None,
                  tech_params=None,
                  tracer=None,
+                 devices: Optional[Sequence[jax.Device]] = None,
                  **server_kw):
+        if devices is not None:
+            if len(devices) != len(cluster.chips):
+                raise ValueError(f"{len(devices)} devices for "
+                                 f"{len(cluster.chips)} dies")
+            if server_factory is not None:
+                raise ValueError("devices places default-built replicas; "
+                                 "a server_factory places its own")
         self.cluster = cluster
         self.model = model
         self.params = params
@@ -99,14 +113,16 @@ class ClusterRouter:
         self.rejected: List[Request] = []
         self.migrations = 0  # cross-die continuation re-admissions
         self._util_samples: Dict[str, List[float]] = {}
-        for spec in cluster.chips:
+        for i, spec in enumerate(cluster.chips):
             policy = ChipPolicy(spec, tech_params)
             self.policies[spec.name] = policy
             n_slots = slots[spec.name] if isinstance(slots, dict) else slots
             if server_factory is not None:
                 srv = server_factory(spec.name, spec, policy)
             else:
-                srv = BatchedServer(model, params, slots=n_slots,
+                die_params = params if devices is None \
+                    else jax.device_put(params, devices[i])
+                srv = BatchedServer(model, die_params, slots=n_slots,
                                     max_len=max_len, chip_policy=policy,
                                     clock=clock, **server_kw)
             if tracer is not None:  # custom factories keep their own wiring

@@ -24,7 +24,8 @@ Exactness arguments (documented per DESIGN.md §2):
     through 53 bits, so we use TwoSum + round-to-odd before the final RNE
     (round-to-odd at 53 bits then RNE to <=24 bits is exact since 53 >= 26).
   * DP fused fma: Boldo-Melquiond emulation, exact barring extreme
-    over/underflow; property-tested against math.fma (CPython 3.13).
+    over/underflow; property-tested against an exact-rational FMA
+    (``repro.numerics.accuracy.rne_fraction``).
 
 All public functions run under a local x64 context so the framework itself
 never flips global jax config.
@@ -46,11 +47,11 @@ from repro.core.formats import FP32, FloatFormat
 
 
 def _with_x64(fn: Callable) -> Callable:
-    """Run ``fn`` (and its tracing) under jax.experimental.enable_x64."""
+    """Run ``fn`` (and its tracing) under ``jax.enable_x64(True)``."""
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             return fn(*args, **kwargs)
 
     return wrapper

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 from repro.core.formats import FloatFormat, quantize
 
 STYLES = ("fused", "cascade", "cascade_fwd")
@@ -87,8 +86,11 @@ def fma_emu_matmul(
     m, kdim = a.shape
     _, n = b.shape
 
-    # pad to tile multiples; zero rows/cols quantize to zero and are exact
-    # no-ops under every accumulation style.
+    # An output smaller than a tile is one full-extent block: no padding, so
+    # each k-step is the same (m, bk) @ (bk, n) dot as kernels/ref.py's and
+    # the two agree bitwise.  Otherwise pad to tile multiples; zero rows/cols
+    # quantize to zero and are exact no-ops under every accumulation style.
+    bm, bn = min(bm, m), min(bn, n)
     pm, pn, pk = (-m) % bm, (-n) % bn, (-kdim) % bk
     a_p = jnp.pad(a.astype(jnp.float32), ((0, pm), (0, pk)))
     b_p = jnp.pad(b.astype(jnp.float32), ((0, pk), (0, pn)))
@@ -108,7 +110,7 @@ def fma_emu_matmul(
         out_shape=jax.ShapeDtypeStruct((m + pm, n + pn), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(a_p, b_p)

@@ -49,7 +49,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 from repro.core.formats import FloatFormat, _unbiased_exp_f32, quantize
 
 STYLES = ("fused", "cascade", "cascade_fwd")
@@ -73,9 +72,12 @@ def _pow2_scale(x: jax.Array, fmt: FloatFormat):
 
     Both factors are exact powers of two built from exponent bits, so
     ``x * inv`` and ``part * scale`` are exact f32 operations: per-tile
-    dequant adds no rounding of its own.
+    dequant adds no rounding of its own.  They are (1, 1) arrays, not
+    scalars: the TPU compiler bitcasts vectors only.
     """
-    e = _unbiased_exp_f32(jnp.max(jnp.abs(x)))
+    amax = jnp.max(jnp.max(jnp.abs(x), axis=1, keepdims=True), axis=0,
+                   keepdims=True)
+    e = _unbiased_exp_f32(amax)
     scale_exp = jnp.clip(e - jnp.clip(e, fmt.emin, fmt.emax - 1), -126, 126)
     scale = lax.bitcast_convert_type(
         ((scale_exp + 127).astype(jnp.uint32) << jnp.uint32(23)), jnp.float32)
@@ -185,7 +187,7 @@ def fused_qmm(
         out_shape=jax.ShapeDtypeStruct((nb, m + pm, n + pn), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -260,8 +262,10 @@ def _flash_block_update(carry, q_blk, k_blk, v_blk, mask, *, scale: float,
     kernel and its ref twin.
 
     q/k/v blocks are (bq|bk, D) f32 for one (batch, head); ``mask`` is
-    (bq, bk).  With ``fmt`` set, q/k/v are rounded to the format per block
-    (with optional exact pow2 scaling) and each partial dot is dequantized
+    (bq, bk); the running max ``m`` and denominator ``l`` are (bq, 1)
+    columns, which keep every value 2-D for the TPU's vector layouts.  With
+    ``fmt`` set, q/k/v are rounded to the format per block (with optional
+    exact pow2 scaling) and each partial dot is dequantized
     before it enters the f32 online-softmax state — the low-precision tensors
     never leave the block.
     """
@@ -279,27 +283,29 @@ def _flash_block_update(carry, q_blk, k_blk, v_blk, mask, *, scale: float,
         s = s * (sq * sk)
     s = s * scale
     s_m = jnp.where(mask, s, NEG_INF)
-    m_new = jnp.maximum(m, jnp.max(s_m, axis=-1))
+    m_new = jnp.maximum(m, jnp.max(s_m, axis=-1, keepdims=True))
     m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-    p = jnp.exp(s - m_safe[:, None]) * mask
+    p = jnp.exp(s - m_safe) * mask
     corr = jnp.exp(jnp.minimum(m - m_safe, 0.0)) * (m > NEG_INF / 2)
-    l_new = l * corr + jnp.sum(p, axis=-1)
+    l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
     if fmt is not None:
         # the probability operand register: p is in [0, 1], no scale needed
         p = quantize(p, fmt)
     pv = jnp.dot(p, qv, preferred_element_type=jnp.float32)
     if sv is not None:
         pv = pv * sv
-    acc_new = acc * corr[:, None] + pv
+    acc_new = acc * corr + pv
     return m_new, l_new, acc_new
 
 
 def _flash_mask(q_pos, k_pos, *, causal: bool, window: int, kv_len: int):
-    m = (k_pos[None, :] < kv_len)
+    """(bq, bk) mask from a (bq, 1) column of query and a (1, bk) row of key
+    positions."""
+    m = k_pos < kv_len
     if causal:
-        m = m & (k_pos[None, :] <= q_pos[:, None])
+        m = m & (k_pos <= q_pos)
     if window:
-        m = m & (k_pos[None, :] > q_pos[:, None] - window)
+        m = m & (k_pos > q_pos - window)
     return m
 
 
@@ -314,21 +320,21 @@ def _fused_flash_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    q_pos = q_offset + qi * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)[:, 0]
-    k_pos = kj * bk + lax.broadcasted_iota(jnp.int32, (bk, 1), 0)[:, 0]
+    q_pos = q_offset + qi * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    k_pos = kj * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
     mask = _flash_mask(q_pos, k_pos, causal=causal, window=window,
                        kv_len=kv_len)
-    carry = (m_s[:, 0], l_s[:, 0], acc_s[...])
+    carry = (m_s[:, :1], l_s[:, :1], acc_s[...])
     m_new, l_new, acc_new = _flash_block_update(
         carry, q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], mask,
         scale=scale, fmt=fmt, scaled=scaled)
-    m_s[...] = jnp.broadcast_to(m_new[:, None], m_s.shape)
-    l_s[...] = jnp.broadcast_to(l_new[:, None], l_s.shape)
+    m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
+    l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
     acc_s[...] = acc_new
 
     @pl.when(kj == nk - 1)
     def _flush():
-        out = acc_s[...] / jnp.maximum(l_s[:, 0], 1e-30)[:, None]
+        out = acc_s[...] / jnp.maximum(l_s[:, :1], 1e-30)
         if out_fmt is not None:
             out = quantize(out, out_fmt)
         o_ref[0, 0] = out
@@ -406,7 +412,7 @@ def fused_flash_attention(
             pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -454,12 +460,12 @@ def fused_flash_ref(
         hk = h // G
         q_rows = []
         for qi in range(nq):
-            q_pos = q_offset + qi * bq + jnp.arange(bq)
-            m = jnp.full((B, bq), NEG_INF, jnp.float32)
-            l = jnp.zeros((B, bq), jnp.float32)
+            q_pos = q_offset + qi * bq + jnp.arange(bq)[:, None]
+            m = jnp.full((B, bq, 1), NEG_INF, jnp.float32)
+            l = jnp.zeros((B, bq, 1), jnp.float32)
             acc = jnp.zeros((B, bq, D), jnp.float32)
             for kj in range(nk):
-                k_pos = kj * bk + jnp.arange(bk)
+                k_pos = kj * bk + jnp.arange(bk)[None, :]
                 mask = _flash_mask(q_pos, k_pos, causal=causal,
                                    window=window, kv_len=kv_len)
                 for bb in range(B):
@@ -472,7 +478,7 @@ def fused_flash_ref(
                     m = m.at[bb].set(mb)
                     l = l.at[bb].set(lb)
                     acc = acc.at[bb].set(ab)
-            out = acc / jnp.maximum(l, 1e-30)[..., None]
+            out = acc / jnp.maximum(l, 1e-30)
             if out_fmt is not None:
                 out = quantize(out, out_fmt)
             q_rows.append(out)
@@ -527,23 +533,23 @@ def fused_flash_scan(
     def one_head(qh, kh, vh):
         def q_step(_, qi_blk):
             qi, q_blk = qi_blk
-            q_pos = q_offset + qi * bq + jnp.arange(bq)
+            q_pos = q_offset + qi * bq + jnp.arange(bq)[:, None]
 
             def kv_step(carry, kj_blk):
                 kj, k_blk, v_blk = kj_blk
-                k_pos = kj * bk + jnp.arange(bk)
+                k_pos = kj * bk + jnp.arange(bk)[None, :]
                 mask = _flash_mask(q_pos, k_pos, causal=causal,
                                    window=window, kv_len=kv_len_)
                 return _flash_block_update(carry, q_blk, k_blk, v_blk, mask,
                                            scale=scale, fmt=fmt,
                                            scaled=scaled), None
 
-            init = (jnp.full((bq,), NEG_INF, jnp.float32),
-                    jnp.zeros((bq,), jnp.float32),
+            init = (jnp.full((bq, 1), NEG_INF, jnp.float32),
+                    jnp.zeros((bq, 1), jnp.float32),
                     jnp.zeros((bq, D), jnp.float32))
             (m, l, acc), _ = lax.scan(kv_step, init,
                                       (jnp.arange(nk), kh, vh))
-            out = acc / jnp.maximum(l, 1e-30)[:, None]
+            out = acc / jnp.maximum(l, 1e-30)
             if out_fmt is not None:
                 out = quantize(out, out_fmt)
             return None, out
@@ -592,7 +598,7 @@ def _ssm_scan_quant_kernel(a_ref, b_ref, c_ref, y_ref, h_ref, hstate, *,
 @functools.partial(jax.jit, static_argnames=("fmt", "out_fmt", "chunk", "bd",
                                              "interpret"))
 def ssm_scan_quantized(a, b, c, *, fmt: FloatFormat | None,
-                       out_fmt: FloatFormat | None = None, chunk: int = 64,
+                       out_fmt: FloatFormat | None = None, chunk: int = 16,
                        bd: int = 256, interpret: bool = False):
     """Quantized selective scan: operands rounded to ``fmt`` on VMEM entry.
 
@@ -602,7 +608,8 @@ def ssm_scan_quantized(a, b, c, *, fmt: FloatFormat | None,
     through the format's operand registers, and ``out_fmt`` optionally
     rounds the readout.  Rounding is elementwise, so — unlike the matmul
     kernels — the quantization is tiling-independent and the bitwise ref is
-    ``ssm_scan_quantized_ref`` regardless of (chunk, bd).
+    ``ssm_scan_quantized_ref`` regardless of (chunk, bd).  The default
+    tiles fit v5e's scoped VMEM as in ``kernels/ssm_scan.ssm_scan``.
     """
     B, S, D, N = a.shape
     bd = min(bd, D)
@@ -629,7 +636,7 @@ def ssm_scan_quantized(a, b, c, *, fmt: FloatFormat | None,
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a.astype(jnp.float32), b.astype(jnp.float32), c.astype(jnp.float32))
     return y, h

@@ -14,6 +14,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.formats import FloatFormat, quantize
 
+BLOCK_COLS = 512
+
 
 def _quantize_kernel(x_ref, o_ref, *, fmt: FloatFormat):
     o_ref[...] = quantize(x_ref[...], fmt)
@@ -29,20 +31,25 @@ def quantize_2d(
     block_rows: int = 256,
     interpret: bool = False,
 ) -> jax.Array:
-    """Round a 2D f32 array onto fmt's grid. Lane dim padded to 128."""
+    """Round a 2D f32 array onto fmt's grid.
+
+    Tiles are (block_rows, up to BLOCK_COLS lanes): the lane dimension is
+    tiled too, so a tile's VMEM footprint does not grow with the row length
+    (a whole 2048-wide row per tile overflows v5e's 16 MiB scoped VMEM).
+    """
     if x.ndim != 2:
         raise ValueError(f"quantize_2d wants 2D, got {x.shape}")
     m, n = x.shape
     bm = min(block_rows, max(8, m))
-    pm, pn = (-m) % bm, (-n) % 128
+    bn = min(BLOCK_COLS, n + (-n) % 128)
+    pm, pn = (-m) % bm, (-n) % bn
     x_p = jnp.pad(x.astype(jnp.float32), ((0, pm), (0, pn)))
-    gm = (m + pm) // bm
-    bn = n + pn
+    gm, gn = (m + pm) // bm, (n + pn) // bn
     out = pl.pallas_call(
         functools.partial(_quantize_kernel, fmt=fmt),
-        grid=(gm,),
-        in_specs=[pl.BlockSpec((bm, bn), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bm, bn), lambda i: (i, 0)),
+        grid=(gm, gn),
+        in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m + pm, n + pn), jnp.float32),
         interpret=interpret,
     )(x_p)

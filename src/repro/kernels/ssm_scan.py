@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _ssm_scan_kernel(a_ref, b_ref, c_ref, y_ref, h_ref, hstate,
                      *, nchunks: int, chunk: int):
@@ -56,13 +54,15 @@ def _ssm_scan_kernel(a_ref, b_ref, c_ref, y_ref, h_ref, hstate,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "bd", "interpret"))
-def ssm_scan(a, b, c, *, chunk: int = 64, bd: int = 256,
+def ssm_scan(a, b, c, *, chunk: int = 16, bd: int = 256,
              interpret: bool = False):
     """a, b: (B, S, D, N) decay/injection; c: (B, S, N) readout.
 
     Returns (y (B,S,D) f32, h_last (B,D,N) f32).  S % chunk == 0 and
     D % bd == 0 are required (pad at the caller; the model layers use
-    power-of-two D and S).
+    power-of-two D and S).  The default (chunk, bd) fits v5e's 16 MiB of
+    scoped VMEM for d_state up to 128: the a and b tiles are lane-padded to
+    (16, 256, 128) f32, 2 MiB each, 8 MiB double-buffered.
     """
     B, S, D, N = a.shape
     if S % chunk or D % min(bd, D):
@@ -89,7 +89,7 @@ def ssm_scan(a, b, c, *, chunk: int = 64, bd: int = 256,
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a.astype(jnp.float32), b.astype(jnp.float32), c.astype(jnp.float32))
     return y, h

@@ -45,6 +45,9 @@ def main():
     from repro.cluster import (ClusterRouter, ClusterSpec, RequestClass,
                                SimClock, TraceConfig, generate,
                                latency_stats, replay)
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     def unit(name, fmt, rel_err, e_pj):
         metrics = dict(freq_ghz=1.0, cycle_ns=1.0, p_total_mw=2e3 * e_pj,

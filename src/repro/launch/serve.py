@@ -1,21 +1,42 @@
 """Serving launcher: batched continuous-batching engine over any assigned
-architecture (reduced config on CPU).
+architecture, at its published config (``--reduced`` for a CPU-sized one).
 
   PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
-      --requests 8 --slots 4
+      --reduced --requests 8 --slots 4
 
 With ``--chaos`` the launcher runs the fault-tolerant engine over a
 tiered two-fleet die and injects one seeded fault mid-run, printing the
 resilience report (see docs/resilience.md):
 
-  PYTHONPATH=src python -m repro.launch.serve --chaos kill
+  PYTHONPATH=src python -m repro.launch.serve --reduced --chaos kill
 """
 import argparse
+
+
+def load_model(arch: str, *, reduced: bool, seed: int = 0):
+    """(model, params) for ``arch``: the published config, or its CPU-sized
+    ``reduced()`` variant, with random weights from ``seed`` initialised on
+    the default device in the config's dtype."""
+    import jax
+
+    from repro.configs.base import get_config
+    from repro.models import LM
+
+    cfg = get_config(arch)
+    if reduced:
+        cfg = cfg.reduced()
+    if cfg.frontend == "audio":
+        raise SystemExit("musicgen prompts require the frame-embed stub")
+    model = LM(cfg)
+    return model, jax.jit(model.init)(jax.random.key(seed))
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the arch's reduced (CPU-sized) config "
+                         "instead of its published one")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--new-tokens", type=int, default=12)
@@ -45,18 +66,14 @@ def main():
                          "chrome://tracing or ui.perfetto.dev)")
     args = ap.parse_args()
 
-    import jax
     import numpy as np
 
-    from repro.configs.base import get_config
-    from repro.models import LM
+    from repro.launch.compile_cache import use_compile_cache
     from repro.serve.engine import BatchedServer, Request
 
-    cfg = get_config(args.arch).reduced()
-    if cfg.frontend == "audio":
-        raise SystemExit("musicgen prompts require the frame-embed stub")
-    model = LM(cfg)
-    params = model.init(jax.random.key(0))
+    use_compile_cache()
+    model, params = load_model(args.arch, reduced=args.reduced)
+    cfg = model.cfg
     stops = () if args.stop_token is None else (args.stop_token,)
 
     tracer = None

@@ -299,15 +299,18 @@ class BatchedServer:
         # ring (sliding-window) KV caches wrap; everything else caps the
         # total per-slot length at the cache width
         self._ring = bool(self.cfg.window) and self.cfg.family != "hybrid"
-        cache = model.init_cache(slots, max_len)
+        # device-resident slot state, on the device that holds the params
+        # (a ClusterRouter places each die's replica on its own device)
+        (device,) = jax.tree.leaves(params)[0].devices()
+        with jax.default_device(device):
+            cache = model.init_cache(slots, max_len)
+            self.cache = DecodeCache(cache.data, jnp.zeros(slots, jnp.int32))
+            self._next_tok = jnp.full((slots, 1), pad_id, jnp.int32)
+            self._budget = jnp.zeros(slots, jnp.int32)
+            self._active_mask = jnp.zeros(slots, bool)
         self._len_cap = None
         if "k" in cache.data and not self._ring:
             self._len_cap = cache.data["k"].shape[2]
-        # device-resident slot state
-        self.cache = DecodeCache(cache.data, jnp.zeros(slots, jnp.int32))
-        self._next_tok = jnp.full((slots, 1), pad_id, jnp.int32)
-        self._budget = jnp.zeros(slots, jnp.int32)
-        self._active_mask = jnp.zeros(slots, bool)
         # host-side slot table / queues / fleet plan
         self._active: List[Optional[Request]] = [None] * slots
         # total tokens the slot's request will get (1 + its device budget;
@@ -1378,15 +1381,24 @@ def greedy_decode(model: LM, params, prompt: np.ndarray, n_new: int,
     """
     stops = set(int(s) for s in stop_tokens)
     max_len = max_len or (len(prompt) + n_new)
-    last, cache = model.prefill(params, jnp.asarray(prompt[None]),
-                                max_len=max_len)
-    out = [int(jnp.argmax(last, -1)[0])]
-    tok = jnp.asarray([[out[-1]]], jnp.int32)
+    nxt, cache = _greedy_prefill_jit(model, max_len, params,
+                                     jnp.asarray(prompt[None]))
+    out = [int(nxt[0])]
     for _ in range(n_new - 1):
         if out[-1] in stops:
             break
-        logits, cache = model.decode_step(params, cache, tok)
-        nxt = int(jnp.argmax(logits[:, -1], -1)[0])
-        out.append(nxt)
-        tok = jnp.asarray([[nxt]], jnp.int32)
+        nxt, cache = _greedy_step_jit(model, params, cache, nxt[:, None])
+        out.append(int(nxt[0]))
     return out
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _greedy_prefill_jit(model, max_len, params, tokens):
+    last, cache = model.prefill(params, tokens, max_len=max_len)
+    return jnp.argmax(last, -1).astype(jnp.int32), cache
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _greedy_step_jit(model, params, cache, tok):
+    logits, cache = model.decode_step(params, cache, tok)
+    return jnp.argmax(logits[:, -1], -1).astype(jnp.int32), cache

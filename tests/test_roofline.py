@@ -19,8 +19,9 @@ def test_scan_flops_and_collectives_exact():
     run_multidevice("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
+        from repro.launch.mesh import make_host_mesh
         from repro.roofline.hlo_parse import analyze_hlo
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_host_mesh(data=2, model=2)
         W = jax.ShapeDtypeStruct((2048, 2048), jnp.float32)
         x = jax.ShapeDtypeStruct((256, 2048), jnp.float32)
         def f(w, x):

@@ -1,13 +1,16 @@
-"""Bit-exact FMA/CMA semantics vs math.fma and exactness oracles."""
+"""Bit-exact FMA/CMA semantics vs the exact-rational oracle."""
 import math
+from fractions import Fraction
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import assume, given, settings, strategies as st
 
 from repro.core import softfloat as sf
-from repro.core.formats import BF16, FP16, FP32, TF32
+from repro.core.formats import BF16, FP16, FP32, FP64, TF32
+from repro.numerics.accuracy import rne_fraction
 
 f64s = st.floats(allow_nan=False, allow_infinity=False,
                  min_value=-1e15, max_value=1e15)
@@ -17,11 +20,16 @@ def f32(x):
     return float(np.float32(x))
 
 
+def exact_fma(a, b, c, fmt):
+    """a*b + c computed exactly in rationals, rounded once onto ``fmt``."""
+    return float(rne_fraction(Fraction(a) * Fraction(b) + Fraction(c), fmt))
+
+
 @settings(max_examples=300, deadline=None)
 @given(f64s, f64s, f64s)
 def test_sp_fma_matches_math_fma(a, b, c):
     a, b, c = f32(a), f32(b), f32(c)
-    ref = f32(math.fma(a, b, c))
+    ref = exact_fma(a, b, c, FP32)
     # XLA:CPU (and TPU) are DAZ/FTZ: subnormal f32 in/outputs act as zero
     assume(all(_normal_f32(v) for v in (a, b, c, ref)))
     ours = float(sf.sf_fma(jnp.float32(a), jnp.float32(b), jnp.float32(c),
@@ -55,7 +63,7 @@ def _normal_range(*vals):
 def test_dp_fma_matches_math_fma(a, b, c):
     assume(_normal_range(a * b, a * b + c))
     ours = float(sf.dp_fma(np.float64(a), np.float64(b), np.float64(c)))
-    ref = math.fma(a, b, c)
+    ref = exact_fma(a, b, c, FP64)
     assert ours == ref or (math.isnan(ours) and math.isnan(ref))
 
 
@@ -67,7 +75,7 @@ def test_dp_fma_cancellation(a, b):
     c = -(a * b) * (1 + 2 ** -50)
     assume(_normal_range(a * b, a * b + c))
     ours = float(sf.dp_fma(np.float64(a), np.float64(b), np.float64(c)))
-    ref = math.fma(a, b, c)
+    ref = exact_fma(a, b, c, FP64)
     assert ours == ref or (math.isnan(ours) and math.isnan(ref))
 
 
@@ -116,13 +124,12 @@ def test_dot_dispatch():
 
 def test_two_sum_exact():
     rng = np.random.default_rng(2)
-    with __import__("jax").experimental.enable_x64():
+    with jax.enable_x64(True):
         a = jnp.asarray(rng.standard_normal(1000) * 1e10)
         b = jnp.asarray(rng.standard_normal(1000) * 1e-10)
         s, e = sf._two_sum(a, b)
         # s + e == a + b exactly: check via arbitrary-precision floats
         for i in range(0, 1000, 97):
-            import fractions
-            lhs = fractions.Fraction(float(s[i])) + fractions.Fraction(float(e[i]))
-            rhs = fractions.Fraction(float(a[i])) + fractions.Fraction(float(b[i]))
+            lhs = Fraction(float(s[i])) + Fraction(float(e[i]))
+            rhs = Fraction(float(a[i])) + Fraction(float(b[i]))
             assert lhs == rhs

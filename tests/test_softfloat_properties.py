@@ -127,8 +127,10 @@ def test_cma_matches_two_rounding_reference(fmt, data):
 
 def test_fma_vs_cma_divergence_case():
     """Deterministic witness that the oracle distinguishes one rounding from
-    two: the rounded product loses exactly the bits the sum needs."""
-    a = 1.0 + 2.0 ** -7
+    two: the rounded product loses exactly the bits the sum needs.  With
+    a = 1 + 2**-5, a*a - 1 = 2**-4 + 2**-10 is on the bf16 grid, but the
+    bf16-rounded product 1 + 2**-4 has already dropped the 2**-10 term."""
+    a = 1.0 + 2.0 ** -5
     fused = float(sf.sf_fma(jnp.float32(a), jnp.float32(a),
                             jnp.float32(-1.0), BF16))
     casc = float(sf.sf_cma(jnp.float32(a), jnp.float32(a),
@@ -160,7 +162,7 @@ finite_f64 = st.floats(allow_nan=False, allow_infinity=False,
 @settings(max_examples=250, deadline=None)
 @given(x=finite_f64)
 def test_quantize64_idempotent(fmt, x):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         q1 = float(sf.quantize64(jnp.float64(x), fmt))
         q2 = float(sf.quantize64(jnp.float64(q1), fmt))
         assert q1 == q2  # finite input never rounds to NaN; inf == inf
@@ -173,5 +175,5 @@ def test_quantize64_fixes_grid_points(fmt, data):
     """Every on-grid value is its own rounding (grid points are fixed
     points), tying the input strategy to quantize64's grid definition."""
     x = data.draw(on_grid(fmt))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         assert float(sf.quantize64(jnp.float64(x), fmt)) == x
