@@ -69,7 +69,7 @@ import numpy as np
 
 from repro.faults import UnitFault
 from repro.models import LM, DecodeCache
-from repro.telemetry.tracer import NULL_TRACER
+from repro.telemetry.tracer import NULL_SPAN, NULL_TRACER
 from repro.telemetry.tracer import Event as TraceEvent
 
 
@@ -344,16 +344,22 @@ class BatchedServer:
         self._slot_fleet = {s: name for name, ids in self._fleets.items()
                             for s in ids}
         # --- telemetry -------------------------------------------------
-        # The tracer records span trees + metric timelines on the injected
-        # clock (see repro.telemetry).  Default is the no-op NULL_TRACER:
-        # every instrumentation site below is guarded by ``tracer.enabled``
-        # so the disabled hot path pays one attribute read per site
-        # (overhead asserted in benchmarks/telemetry_bench.py).
+        # The tracer records span trees, step spans and metric timelines on
+        # the injected clock (see repro.telemetry).  Default is the no-op
+        # NULL_TRACER: every instrumentation site below is guarded by
+        # ``tracer.enabled`` so the disabled hot path pays one attribute
+        # read per site (and enters the shared NULL_SPAN around a step
+        # phase).  The recording cost on a TPU v5e is in PERF.md.
         self.tracer = tracer if tracer is not None else NULL_TRACER
         #: die/site label stamped on spans and metric samples (the cluster
         #: router sets it to the die name)
         self.trace_site = ""
         self.reset_run_counters()
+
+    def _span(self, name: str, **attrs):
+        """A step-phase span on the engine's clock and site (callers guard
+        with ``tracer.enabled`` and enter ``NULL_SPAN`` otherwise)."""
+        return self.tracer.span(name, self._clock, self.trace_site, **attrs)
 
     # ------------------------------------------------------- chip telemetry
     def _charge_unit(self, req: Request, unit, flops: float,
@@ -807,8 +813,11 @@ class BatchedServer:
                                   self._clock(), site=self.trace_site,
                                   fleet=fleet)
 
-    def _admit(self, now: float):
+    def _admit(self, now: float) -> int:
+        """Monolithic admission: batched prefill of queued requests into
+        free lanes.  Returns how many were admitted."""
         self._unpark()
+        admitted = 0
         for fleet, slot_ids in self._fleets.items():
             if not self._fleet_in_service(fleet):
                 continue  # the resilience layer drains/re-routes its queue
@@ -838,6 +847,8 @@ class BatchedServer:
                 if not batch:
                     break
                 self._admit_batch(batch, free[:len(batch)], bucket)
+                admitted += len(batch)
+        return admitted
 
     def _admit_batch(self, reqs: List[Request], slot_ids: List[int],
                      bucket: int):
@@ -868,11 +879,13 @@ class BatchedServer:
             self.model, self._ring, self.params, self.cache, self._next_tok,
             self._active_mask, self._budget, jnp.asarray(tokens),
             jnp.asarray(true_lens), jnp.asarray(ids), jnp.asarray(budgets))
-        first = np.asarray(first)  # one host sync per admitted batch
+        tr = self.tracer
+        with (self._span("engine.sync", program="_admit_jit")
+              if tr.enabled else NULL_SPAN):
+            first = np.asarray(first)  # one host sync per admitted batch
         self.host_syncs += 1
         now = self._clock()
         dead = []
-        tr = self.tracer
         for j, (req, p, slot) in enumerate(zip(reqs, prompts, slot_ids)):
             if tr.enabled:
                 tr.begin_attempt(req.uid, now, site=self.trace_site,
@@ -924,13 +937,15 @@ class BatchedServer:
                 np.asarray(dead, np.int32)].set(False)
 
     # --------------------------------------- continuous batching scheduler
-    def _seat(self, now: float):
+    def _seat(self, now: float) -> int:
         """Continuous-batching admission: move queued requests into free
         lanes *immediately* (FIFO per in-service fleet) without touching
         device state — seated lanes prefill chunk by chunk via
         ``_advance_prefills`` and only join the decode dispatch once their
-        final chunk arms the slot on device."""
+        final chunk arms the slot on device.  Returns how many were
+        seated."""
         self._unpark()
+        seated = 0
         for fleet, slot_ids in self._fleets.items():
             if not self._fleet_in_service(fleet):
                 continue
@@ -950,14 +965,16 @@ class BatchedServer:
                 self._slot_pf_budget[slot] = max(cap, 0)
                 self._slot_quota[slot] = 1 + self._slot_pf_budget[slot]
                 self._slot_replay[slot] = 0
+                seated += 1
                 if self.tracer.enabled:
                     self.tracer.begin_attempt(
                         req.uid, now, site=self.trace_site,
                         fleet=self._slot_fleet.get(slot, ""), slot=slot)
                     self.tracer.event(req.uid, TraceEvent.SEAT, now,
                                       slot=slot)
+        return seated
 
-    def _advance_prefills(self, now: float):
+    def _advance_prefills(self, now: float) -> int:
         """Advance every mid-prefill lane by one chunk (<= prefill_chunk
         tokens), grouped by padded chunk width so same-shape chunks share
         one dispatch and one compiled program.  Attention families pad the
@@ -966,7 +983,8 @@ class BatchedServer:
         (the conv carry integrates raw inputs, so pads would corrupt it).
         A lane whose chunk completes the prompt gets its decode slot state
         armed in the same dispatch; its first output token is committed
-        here (one host sync, only on steps with finishing lanes)."""
+        here (one host sync, only on steps with finishing lanes).  Returns
+        how many lanes advanced."""
         C = self.prefill_chunk
         lanes = sorted(self._prefill_pos)
         if self.prefill_token_budget is not None and lanes:
@@ -986,81 +1004,103 @@ class BatchedServer:
             cb = min(bucket_length(clen, lo=self.min_bucket), C) \
                 if self._bucketed else clen
             groups.setdefault(cb, []).append(s)
+        tr = self.tracer
         for cb, slots in sorted(groups.items()):
-            M = len(slots)
-            Mb = 1
-            while Mb < M:  # pow2 lane pad: chunk programs are shared
-                Mb *= 2    # across prompts and steps
-            tokens = np.full((Mb, cb), self.pad_id, np.int32)
-            offs = np.zeros(Mb, np.int32)
-            clens = np.ones(Mb, np.int32)
-            ids = np.full(Mb, self.slots, np.int32)  # OOB pads: dropped
-            final_ids = np.full(Mb, self.slots, np.int32)
-            budgets = np.zeros(Mb, np.int32)
-            finals: List[int] = []
-            for j, s in enumerate(slots):
-                req = self._active[s]
-                p = np.asarray(req.prompt)
-                off = self._prefill_pos[s]
-                clen = min(C, len(p) - off)
-                tokens[j, :clen] = p[off:off + clen]
-                offs[j] = off
-                clens[j] = clen
-                ids[j] = s
-                if off + clen == len(p):
-                    final_ids[j] = s
-                    budgets[j] = self._slot_pf_budget[s]
-                    finals.append(j)
-            (self.cache, self._next_tok, self._active_mask, self._budget,
-             first) = _chunk_jit(
-                self.model, self.params, self.cache, self._next_tok,
-                self._active_mask, self._budget, jnp.asarray(tokens),
-                jnp.asarray(offs), jnp.asarray(clens), jnp.asarray(ids),
-                jnp.asarray(final_ids), jnp.asarray(budgets))
-            if finals:
+            with (self._span("engine.chunk") if tr.enabled
+                  else NULL_SPAN) as span:
+                self._run_chunk_group(cb, slots, now, span)
+        return len(lanes)
+
+    def _run_chunk_group(self, cb: int, slots: List[int], now: float,
+                         span) -> None:
+        """One ``_chunk_jit`` call over the lanes ``slots``, all at padded
+        chunk width ``cb``, and the first tokens of the lanes it finishes.
+        ``span`` is the recording ``engine.chunk`` span, or None."""
+        C = self.prefill_chunk
+        M = len(slots)
+        Mb = 1
+        while Mb < M:  # pow2 lane pad: chunk programs are shared
+            Mb *= 2    # across prompts and steps
+        tokens = np.full((Mb, cb), self.pad_id, np.int32)
+        offs = np.zeros(Mb, np.int32)
+        clens = np.ones(Mb, np.int32)
+        ids = np.full(Mb, self.slots, np.int32)  # OOB pads: dropped
+        final_ids = np.full(Mb, self.slots, np.int32)
+        budgets = np.zeros(Mb, np.int32)
+        finals: List[int] = []
+        for j, s in enumerate(slots):
+            req = self._active[s]
+            p = np.asarray(req.prompt)
+            off = self._prefill_pos[s]
+            clen = min(C, len(p) - off)
+            tokens[j, :clen] = p[off:off + clen]
+            offs[j] = off
+            clens[j] = clen
+            ids[j] = s
+            if off + clen == len(p):
+                final_ids[j] = s
+                budgets[j] = self._slot_pf_budget[s]
+                finals.append(j)
+        tr = self.tracer
+        if span is not None:
+            span.attrs.update(shape=[Mb, cb], finals=len(finals),
+                              lanes=[[int(offs[j]), int(clens[j])]
+                                     for j in range(M)])
+        (self.cache, self._next_tok, self._active_mask, self._budget,
+         first) = _chunk_jit(
+            self.model, self.params, self.cache, self._next_tok,
+            self._active_mask, self._budget, jnp.asarray(tokens),
+            jnp.asarray(offs), jnp.asarray(clens), jnp.asarray(ids),
+            jnp.asarray(final_ids), jnp.asarray(budgets))
+        t_first = now
+        if finals:
+            with (self._span("engine.sync", program="_chunk_jit")
+                  if tr.enabled else NULL_SPAN):
                 first = np.asarray(first)  # host sync only when lanes end
-                self.host_syncs += 1
-            dead = []
-            tr = self.tracer
-            for j, s in enumerate(slots):
-                req = self._active[s]
-                clen = int(clens[j])
-                self.prefill_tokens += clen
-                self._charge_unit(req, self._prefill_unit(req),
-                                  self.flops_per_token * clen,
-                                  phase="prefill")
-                if tr.enabled:
-                    tr.event(req.uid, TraceEvent.PREFILL_CHUNK, now,
-                             tokens=clen, offset=int(offs[j]), slot=s)
-                    tr.count("bucket_hit", now,
-                             1.0 if cb == clen else 0.0, self.trace_site)
-                if final_ids[j] == self.slots:
-                    self._prefill_pos[s] = int(offs[j]) + clen
-                    continue
-                # final chunk: the prompt's last logits just produced the
-                # first output token — same commit semantics as
-                # _admit_batch (replay skip, first-token EOS, zero budget)
-                del self._prefill_pos[s]
-                self.tokens_decoded += 1
-                replay = len(req.output)
-                if not replay:
-                    req.output.append(int(first[j]))
-                    if req.first_token_s is None:
-                        req.first_token_s = now
-                    if tr.enabled:  # final chunk committed one token
-                        tr.event(req.uid, TraceEvent.DECODE_DISPATCH, now,
-                                 tokens=1, slot=s, first=True)
-                if budgets[j] == 0 or (not replay
-                                       and int(first[j]) in self._stop_set):
-                    self._finish(req)
-                    self._active[s] = None
-                    if budgets[j] > 0:
-                        dead.append(s)  # free the armed lane on device
-                else:
-                    self._slot_replay[s] = max(replay - 1, 0)
-            if dead:
-                self._active_mask = self._active_mask.at[
-                    np.asarray(dead, np.int32)].set(False)
+            self.host_syncs += 1
+            # the first tokens exist once the sync returns: stamp them
+            # then, as _admit_batch does, not at the step's start
+            t_first = self._clock()
+        dead = []
+        for j, s in enumerate(slots):
+            req = self._active[s]
+            clen = int(clens[j])
+            self.prefill_tokens += clen
+            self._charge_unit(req, self._prefill_unit(req),
+                              self.flops_per_token * clen,
+                              phase="prefill")
+            if tr.enabled:
+                tr.event(req.uid, TraceEvent.PREFILL_CHUNK, now,
+                         tokens=clen, offset=int(offs[j]), slot=s)
+                tr.count("bucket_hit", now,
+                         1.0 if cb == clen else 0.0, self.trace_site)
+            if final_ids[j] == self.slots:
+                self._prefill_pos[s] = int(offs[j]) + clen
+                continue
+            # final chunk: the prompt's last logits just produced the
+            # first output token — same commit semantics as
+            # _admit_batch (replay skip, first-token EOS, zero budget)
+            del self._prefill_pos[s]
+            self.tokens_decoded += 1
+            replay = len(req.output)
+            if not replay:
+                req.output.append(int(first[j]))
+                if req.first_token_s is None:
+                    req.first_token_s = t_first
+                if tr.enabled:  # final chunk committed one token
+                    tr.event(req.uid, TraceEvent.DECODE_DISPATCH, t_first,
+                             tokens=1, slot=s, first=True)
+            if budgets[j] == 0 or (not replay
+                                   and int(first[j]) in self._stop_set):
+                self._finish(req)
+                self._active[s] = None
+                if budgets[j] > 0:
+                    dead.append(s)  # free the armed lane on device
+            else:
+                self._slot_replay[s] = max(replay - 1, 0)
+        if dead:
+            self._active_mask = self._active_mask.at[
+                np.asarray(dead, np.int32)].set(False)
 
     @property
     def decode_stall_frac(self) -> float:
@@ -1109,10 +1149,6 @@ class BatchedServer:
                            for q in self._queues.values() for r in q)),
                  site)
         tr.count("decode_stall_frac", now, self.decode_stall_frac, site)
-        for name, ids in self._fleets.items():
-            seated = sum(1 for s in ids if self._active[s] is not None)
-            tr.count(f"fleet_util.{name or 'default'}", now,
-                     seated / max(len(ids), 1), site)
 
     # ------------------------------------------------------------ decoding
     def step(self, max_tokens: Optional[int] = None) -> int:
@@ -1121,6 +1157,13 @@ class BatchedServer:
         over the decode-ready slots (up to ``max_tokens`` tokens each,
         default 1).  Returns #seated slots (mid-prefill lanes count: the
         engine is not idle while they stream)."""
+        tr = self.tracer
+        with (self._span("engine.step") if tr.enabled else NULL_SPAN) as span:
+            return self._step(max_tokens, span)
+
+    def _step(self, max_tokens: Optional[int], span) -> int:
+        """``step``'s body; ``span`` is the recording ``engine.step`` span,
+        or None."""
         now = self._clock()
         self._expire_active(now)
         # decode-ready lanes BEFORE admission: if any exist, this step is
@@ -1129,11 +1172,16 @@ class BatchedServer:
         decode_ready = sum(1 for s, r in enumerate(self._active)
                            if r is not None and s not in self._prefill_pos)
         pf0 = self.prefill_tokens
-        if self.prefill_chunk is not None:
-            self._seat(now)
-            self._advance_prefills(now)
-        else:
-            self._admit(now)
+        tr = self.tracer
+        with (self._span("engine.seat") if tr.enabled else NULL_SPAN) as seat:
+            if self.prefill_chunk is not None:
+                seated = self._seat(now)
+            else:
+                seated = self._admit(now)
+        if seat is not None:
+            seat.attrs["seated"] = seated
+        prefill_lanes = self._advance_prefills(now) \
+            if self.prefill_chunk is not None else seated
         pf_delta = self.prefill_tokens - pf0
         contended = decode_ready > 0 and pf_delta > 0
         if contended:
@@ -1141,27 +1189,61 @@ class BatchedServer:
         n_seated = sum(1 for r in self._active if r is not None)
         active_slots = [s for s, r in enumerate(self._active)
                         if r is not None and s not in self._prefill_pos]
-        if self.tracer.enabled:
+        if tr.enabled:
+            span.attrs.update(seated=n_seated, decode_lanes=len(active_slots),
+                              prefill_lanes=prefill_lanes)
             self._sample_metrics(now, n_seated, len(active_slots))
         if not active_slots:
             return n_seated
         n = 1 if max_tokens is None else max(1, int(max_tokens))
         t_dispatch = time.perf_counter()
-        (self.cache, self._next_tok, self._active_mask, self._budget,
-         toks, emitted) = _dispatch_jit(
-            self.model, self.pad_id, n, self.stop_tokens, self.params,
-            self.cache, self._next_tok, self._active_mask, self._budget)
-        # THE host sync: one device_get per N-token dispatch
-        toks_np, emitted_np = jax.device_get((toks, emitted))
+        with (self._span("engine.dispatch", n=n,
+                         lanes=self._dispatch_lanes(active_slots, n))
+              if tr.enabled else NULL_SPAN):
+            (self.cache, self._next_tok, self._active_mask, self._budget,
+             toks, emitted) = _dispatch_jit(
+                self.model, self.pad_id, n, self.stop_tokens, self.params,
+                self.cache, self._next_tok, self._active_mask, self._budget)
+            # THE host sync: one device_get per N-token dispatch
+            with (self._span("engine.sync", program="_dispatch_jit")
+                  if tr.enabled else NULL_SPAN):
+                toks_np, emitted_np = jax.device_get((toks, emitted))
         self.dispatches += 1
         self.host_syncs += 1
         now = self._clock()
+        with (self._span("engine.commit") if tr.enabled
+              else NULL_SPAN) as commit:
+            decode_emitted = self._commit_dispatch(
+                active_slots, toks_np, emitted_np, n, now,
+                time.perf_counter() - t_dispatch)
+        if commit is not None:
+            commit.attrs["tokens"] = decode_emitted
+        if contended:
+            self._contended_decode_tokens += decode_emitted
+        return n_seated
+
+    def _dispatch_lanes(self, active_slots: List[int],
+                        n: int) -> List[List[int]]:
+        """Each decode lane's live work in an ``n``-token dispatch: its
+        cache length and the steps it may still emit."""
+        lanes = []
+        for s in active_slots:
+            r = self._active[s]
+            steps = min(n, self._slot_quota[s] - len(r.output))
+            lanes.append([len(r.prompt) + len(r.output) - 1, max(steps, 0)])
+        return lanes
+
+    def _commit_dispatch(self, active_slots: List[int], toks_np, emitted_np,
+                         n: int, now: float, dispatch_dt_s: float) -> int:
+        """Commit one fetched dispatch: the resilience filter, then each
+        lane's tokens, energy charge and finish or expiry.  Returns the
+        tokens the dispatch emitted."""
         # resilience hook: fault symptoms are applied/detected on the
         # fetched arrays before any token is committed (identity here; the
         # ResilientServer overrides it and may drain slots)
         toks_np, emitted_np = self._filter_dispatch(
             active_slots, np.asarray(toks_np), np.asarray(emitted_np), now,
-            time.perf_counter() - t_dispatch)
+            dispatch_dt_s)
         released = []
         decode_emitted = 0
         tr = self.tracer
@@ -1205,9 +1287,7 @@ class BatchedServer:
         if released:
             self._active_mask = self._active_mask.at[
                 np.asarray(released, np.int32)].set(False)
-        if contended:
-            self._contended_decode_tokens += decode_emitted
-        return n_seated
+        return decode_emitted
 
     def run(self, max_steps: int = 10_000,
             dispatch_tokens: Optional[int] = None) -> List[Request]:

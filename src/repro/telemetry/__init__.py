@@ -11,11 +11,12 @@ from repro.telemetry.export import (coerce_tracer, load_jsonl,
 from repro.telemetry.profile import (MIN_ACTIVITY, TraceSummary,
                                      phases_from_trace, profile_from_trace,
                                      summarize_trace)
-from repro.telemetry.tracer import (NULL_TRACER, Event, NullTracer, Span,
-                                    Tracer)
+from repro.telemetry.tracer import (NULL_SPAN, NULL_TRACER, Event,
+                                    NullTracer, Span, StepSpan, Tracer)
 
 __all__ = [
-    "Event", "NullTracer", "NULL_TRACER", "Span", "Tracer",
+    "Event", "NullTracer", "NULL_SPAN", "NULL_TRACER", "Span", "StepSpan",
+    "Tracer",
     "coerce_tracer", "load_jsonl", "to_chrome_trace", "write_chrome_trace",
     "write_jsonl",
     "MIN_ACTIVITY", "TraceSummary", "phases_from_trace",

@@ -7,25 +7,28 @@ Two formats, both loss-tolerant views of the same ``Tracer`` state:
     directly.  Spans become complete ``"X"`` slices (one track per
     request uid, one process per site), request-scoped events become
     instant ``"i"`` markers on the same track, system events (faults,
-    probes, arrivals) get a dedicated ``system`` track, and metric
+    probes, arrivals) get a dedicated ``system`` track, step spans become
+    ``"X"`` slices on one ``engine`` track per site, and metric
     timelines become ``"C"`` counter tracks.  Timestamps are the
     tracer's clock seconds scaled to microseconds (the format's unit).
 
   * **JSONL** (``write_jsonl``/``load_jsonl``): one self-describing JSON
-    object per line (``{"k": "span" | "metric" | "sys", ...}``), compact
-    enough to commit next to bench results and rich enough that
-    ``load_jsonl`` reconstructs a ``Tracer`` that round-trips spans,
-    metric timelines, and system events — ``profile_from_trace`` accepts
-    either a live tracer or a path to one of these logs.
+    object per line (``{"k": "span" | "step" | "metric" | "sys", ...}``),
+    compact enough to commit next to bench results and rich enough that
+    ``load_jsonl`` reconstructs a ``Tracer`` that round-trips spans, step
+    spans, metric timelines, and system events — ``profile_from_trace``
+    accepts either a live tracer or a path to one of these logs.
 """
 from __future__ import annotations
 
 import json
 from typing import Dict, List, Union
 
-from repro.telemetry.tracer import Span, Tracer
+from repro.telemetry.tracer import Span, StepSpan, Tracer
 
 _US = 1e6  # tracer clock is in seconds; chrome traces want microseconds
+#: thread id of a site's ``engine`` track (request tracks use uids >= 0)
+ENGINE_TID = -1
 
 
 def to_chrome_trace(tracer: Tracer) -> dict:
@@ -33,6 +36,7 @@ def to_chrome_trace(tracer: Tracer) -> dict:
     (see module docstring for the mapping)."""
     events: List[dict] = []
     sites = sorted({s.site for s in tracer.spans} |
+                   {s.site for s in tracer.step_spans} |
                    {site for _, _, site, _ in tracer.system_events} |
                    {site for rows in tracer.metrics.values()
                     for _, site, _ in rows})
@@ -40,7 +44,11 @@ def to_chrome_trace(tracer: Tracer) -> dict:
     for site, pid in pid_of.items():
         events.append(dict(ph="M", name="process_name", pid=pid, tid=0,
                            args=dict(name=site or "serve")))
+    for site in sorted({s.site for s in tracer.step_spans}):
+        events.append(dict(ph="M", name="thread_name", pid=pid_of[site],
+                           tid=ENGINE_TID, args=dict(name="engine")))
     end_s = max([s.end_s or s.start_s for s in tracer.spans] +
+                [s.end_s or s.start_s for s in tracer.step_spans] +
                 [t for _, t, _, _ in tracer.system_events] + [0.0])
     for s in tracer.spans:
         pid = pid_of.get(s.site, 1) if sites else 1
@@ -57,6 +65,12 @@ def to_chrome_trace(tracer: Tracer) -> dict:
             events.append(dict(ph="i", name=etype, cat="event", s="t",
                                pid=pid, tid=s.uid, ts=t * _US,
                                args=dict(attrs)))
+    for s in tracer.step_spans:
+        dur = (s.end_s if s.end_s is not None else end_s) - s.start_s
+        events.append(dict(ph="X", name=s.name, cat="engine",
+                           pid=pid_of[s.site], tid=ENGINE_TID,
+                           ts=s.start_s * _US, dur=max(dur, 0.0) * _US,
+                           args=dict(s.attrs)))
     for etype, t, site, attrs in tracer.system_events:
         events.append(dict(ph="i", name=etype, cat="system", s="p",
                            pid=pid_of.get(site, 1) if sites else 1,
@@ -88,11 +102,16 @@ def _span_row(s: Span) -> dict:
 
 
 def write_jsonl(tracer: Tracer, path: str) -> str:
-    """One JSON object per line: every span, metric sample, and system
-    event (round-tripped by ``load_jsonl``)."""
+    """One JSON object per line: every span, step span, metric sample, and
+    system event (round-tripped by ``load_jsonl``)."""
     with open(path, "w") as fh:
         for s in tracer.spans:
             fh.write(json.dumps(_span_row(s)) + "\n")
+        for s in tracer.step_spans:
+            fh.write(json.dumps(dict(k="step", id=s.span_id,
+                                     parent=s.parent_id, name=s.name,
+                                     site=s.site, t0=s.start_s, t1=s.end_s,
+                                     attrs=s.attrs)) + "\n")
         for name, rows in tracer.metrics.items():
             for t, site, value in rows:
                 fh.write(json.dumps(dict(k="metric", name=name, t=t,
@@ -130,6 +149,11 @@ def load_jsonl(path: str) -> Tracer:
                 tr._last_attempt[span.uid] = span
                 if span.end_s is None:
                     tr._attempt[span.uid] = span
+        elif kind == "step":
+            tr.step_spans.append(StepSpan(row["id"], row["parent"],
+                                          row["name"], row["site"],
+                                          row["t0"], end_s=row["t1"],
+                                          attrs=row["attrs"]))
         elif kind == "metric":
             tr.metrics.setdefault(row["name"], []).append(
                 (row["t"], row["site"], row["v"]))

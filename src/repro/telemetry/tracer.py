@@ -21,17 +21,26 @@ time and energy, on the stack's own injected clock (sim seconds under
     against the engine's chip-level ledger, including replayed
     continuations and wasted corrupt-dispatch work;
   * **metric timelines**: per-step counter/gauge samples (lane occupancy,
-    queue depth, stall fractions ...) keyed by name and site.
+    queue depth, stall fractions ...) keyed by name and site;
+  * **step spans** (``Tracer.span``): the phases of one engine step
+    (``engine.step`` > ``engine.seat`` / ``engine.chunk`` /
+    ``engine.dispatch`` > ``engine.sync``, ``engine.commit``), each nested
+    in the innermost open one, kept in ``Tracer.step_spans`` beside the
+    request forest.  While it records, each also enters a
+    ``jax.profiler.TraceAnnotation`` of its name, so that under a profiler
+    session the same span lies on the device trace's clock.
 
 The hot path pays nothing when tracing is off: engines default to the
 module-level ``NULL_TRACER`` whose ``enabled`` is False, and every
 instrumentation site is guarded by ``if tracer.enabled:`` — the disabled
-cost is one attribute read per guarded block (asserted < 5% end to end in
-``benchmarks/telemetry_bench.py``).
+cost is one attribute read per guarded block, plus the shared no-op
+``NULL_SPAN`` context around a step phase.  What recording costs on a TPU
+v5e is measured in PERF.md.
 
 Zero dependencies beyond numpy-free stdlib: this module imports nothing
 from the rest of the package, so every layer (engine, resilience, cluster,
-loadgen, launch) can depend on it without cycles.
+loadgen, launch) can depend on it without cycles; ``jax.profiler`` is
+imported only when a step span is recorded.
 """
 from __future__ import annotations
 
@@ -99,6 +108,69 @@ class Span:
             - self.start_s
 
 
+@dataclasses.dataclass
+class StepSpan:
+    """One phase of an engine step, on the engine's clock."""
+
+    span_id: int               # index in ``Tracer.step_spans``
+    parent_id: Optional[int]   # the span it is nested in (None: a step)
+    name: str                  # "engine.step" | "engine.chunk" | ...
+    site: str                  # die name ('' for a bare server)
+    start_s: float
+    end_s: Optional[float] = None
+    attrs: dict = dataclasses.field(default_factory=dict)
+
+    @property
+    def duration_s(self) -> float:
+        return (self.end_s if self.end_s is not None else self.start_s) \
+            - self.start_s
+
+
+class _NoSpan:
+    """The disabled step span: enters to None and records nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+#: the shared no-op context every disabled step-span site enters
+NULL_SPAN = _NoSpan()
+
+
+class _OpenSpan:
+    """Context of one recording step span (see ``Tracer.span``)."""
+
+    __slots__ = ("tracer", "name", "clock", "site", "attrs", "span", "note")
+
+    def __init__(self, tracer: "Tracer", name: str, clock, site: str,
+                 attrs: dict):
+        self.tracer, self.name, self.clock = tracer, name, clock
+        self.site, self.attrs = site, attrs
+
+    def __enter__(self) -> StepSpan:
+        from jax.profiler import TraceAnnotation
+        tr = self.tracer
+        self.note = TraceAnnotation(self.name)
+        self.note.__enter__()
+        parent = tr._open[-1].span_id if tr._open else None
+        self.span = StepSpan(len(tr.step_spans), parent, self.name,
+                             self.site, self.clock(), attrs=self.attrs)
+        tr.step_spans.append(self.span)
+        tr._open.append(self.span)
+        return self.span
+
+    def __exit__(self, *exc):
+        self.span.end_s = self.clock()
+        self.tracer._open.pop()
+        self.note.__exit__(*exc)
+        return False
+
+
 class NullTracer:
     """The disabled tracer: every hook is a no-op and ``enabled`` is False
     so instrumentation sites can skip even argument construction."""
@@ -129,6 +201,9 @@ class NullTracer:
     def system_event(self, type, t, site="", **attrs):
         return None
 
+    def span(self, name, clock, site="", **attrs):
+        return NULL_SPAN
+
 
 #: the process-wide disabled tracer every engine defaults to
 NULL_TRACER = NullTracer()
@@ -149,6 +224,9 @@ class Tracer(NullTracer):
         #: system-scope events (faults, probes, arrivals): not tied to one
         #: request span — (type, t_s, site, attrs)
         self.system_events: List[Tuple[str, float, str, dict]] = []
+        #: step phases in the order they opened (``span``)
+        self.step_spans: List[StepSpan] = []
+        self._open: List[StepSpan] = []
         self._next_id = 0
 
     # ------------------------------------------------------------- spans
@@ -234,6 +312,15 @@ class Tracer(NullTracer):
     def system_event(self, type: str, t: float, site: str = "",
                      **attrs) -> None:
         self.system_events.append((type, t, site, attrs))
+
+    def span(self, name: str, clock, site: str = "",
+             **attrs) -> _OpenSpan:
+        """Context recording one step phase ``name``: stamped on ``clock``
+        as it opens and closes, nested in the innermost open step span, and
+        mirrored into the profiler's trace as a ``TraceAnnotation``.
+        Entering it returns the ``StepSpan``, whose ``attrs`` the caller may
+        complete once the phase has run."""
+        return _OpenSpan(self, name, clock, site, attrs)
 
     # ----------------------------------------------------- introspection
     def roots(self) -> Dict[int, Span]:

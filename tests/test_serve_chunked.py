@@ -233,6 +233,42 @@ def test_ttft_stamps_under_fake_clock(dense):
     assert short.first_token_s - short.submitted_s == 1.0
 
 
+class TickingClock:
+    """A clock that moves one second on every read."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self) -> float:
+        self.t += 1.0
+        return self.t
+
+
+def test_first_token_stamp_follows_the_chunk_that_made_it(dense):
+    """With a clock that ticks on every read, the first token is stamped
+    after the step's start (its first clock read) and no later than the
+    step's end: the stamp is read once the final chunk's token is on the
+    host, not when the step began."""
+    cfg, model, params = dense
+    clock = TickingClock()
+    server = BatchedServer(model, params, slots=2, max_len=MAX_LEN,
+                           prefill_chunk=4, clock=clock)
+    reqs = _requests(cfg, (4, 13), new_tokens=4)
+    for r in reqs:
+        server.submit(r)
+    window = {}
+    while not server.idle():
+        before = clock.t
+        server.step(2)
+        for r in reqs:
+            if r.first_token_s is not None and r.uid not in window:
+                window[r.uid] = (before + 1.0, clock.t)
+    assert set(window) == {r.uid for r in reqs}
+    for r in reqs:
+        start, end = window[r.uid]
+        assert start < r.first_token_s <= end
+
+
 def test_load_report_counts_remaining_tokens(dense):
     """Backlog weights prompt + decode *tokens*: a queued long prompt must
     outweigh a queued short one even at equal request counts."""

@@ -33,11 +33,13 @@ from repro.faults import FaultEvent, FaultInjector, FaultKind
 from repro.models import LM
 from repro.serve.engine import BatchedServer, Request, greedy_decode
 from repro.serve.resilience import ResilienceConfig, ResilientServer
-from repro.telemetry import (Event, NULL_TRACER, Tracer, load_jsonl,
-                             MIN_ACTIVITY, phases_from_trace,
+from repro.telemetry import (Event, NULL_SPAN, NULL_TRACER, Tracer,
+                             load_jsonl, MIN_ACTIVITY, phases_from_trace,
                              profile_from_trace, summarize_trace,
                              to_chrome_trace, write_chrome_trace,
                              write_jsonl)
+
+from repro.telemetry.export import ENGINE_TID
 
 from helpers import FakeClock, make_chip_unit as unit
 
@@ -137,6 +139,44 @@ def test_null_tracer_is_inert():
     assert NULL_TRACER.charge(1, "u", 1.0, 1.0, 0.0) is None
 
 
+def test_null_tracer_span_records_nothing():
+    calls = []
+
+    def clock():
+        calls.append(1)
+        return 0.0
+
+    ctx = NULL_TRACER.span("engine.step", clock, site="eco", seated=1)
+    assert ctx is NULL_SPAN                  # shared, nothing allocated
+    with ctx as span:
+        assert span is None
+    assert calls == []                       # the clock is never read
+    assert not hasattr(NULL_TRACER, "step_spans")
+
+
+def test_step_spans_nest_and_stamp_on_the_given_clock():
+    clock = FakeClock(1.0)
+    tr = Tracer()
+    with tr.span("engine.step", clock, site="eco") as step:
+        clock.t = 2.0
+        with tr.span("engine.dispatch", clock, site="eco", n=4) as disp:
+            with tr.span("engine.sync", clock, site="eco") as sync:
+                clock.t = 3.0
+        disp.attrs["lanes"] = [[5, 4]]
+        clock.t = 4.0
+    with tr.span("engine.step", clock, site="gold") as nxt:
+        pass
+    assert [s.name for s in tr.step_spans] == [
+        "engine.step", "engine.dispatch", "engine.sync", "engine.step"]
+    assert (step.parent_id, disp.parent_id, sync.parent_id,
+            nxt.parent_id) == (None, step.span_id, disp.span_id, None)
+    assert (step.start_s, step.end_s) == (1.0, 4.0)
+    assert (sync.start_s, sync.end_s) == (2.0, 3.0)
+    assert disp.attrs == {"n": 4, "lanes": [[5, 4]]}
+    assert nxt.site == "gold" and nxt.duration_s == 0.0
+    assert tr.spans == [] and tr.check_integrity() == []
+
+
 # ------------------------------------------------------------- exporters
 def _hand_trace():
     tr = Tracer()
@@ -153,6 +193,17 @@ def _hand_trace():
     tr.count("occupancy", 0.2, 0.75, site="eco")
     tr.system_event(Event.FAULT, 0.25, site="eco", unit="decode_eco",
                     kind="kill")
+    return tr
+
+
+def _hand_steps(tr):
+    clock = FakeClock(0.1)
+    for site in ("eco", "gold"):
+        with tr.span("engine.step", clock, site=site, seated=1):
+            with tr.span("engine.chunk", clock, site=site, shape=[1, 4],
+                         lanes=[[0, 4]], finals=1):
+                clock.t += 0.05
+            clock.t += 0.05
     return tr
 
 
@@ -173,10 +224,42 @@ def test_jsonl_round_trip_preserves_everything(tmp_path):
         assert [tuple(e) for e in a.events] == [tuple(e) for e in b.events]
     assert back.metrics == tr.metrics
     assert back.system_events == tr.system_events
+    assert back.step_spans == []
     assert back.check_integrity() == []
     # a re-loaded tracer is live: new spans keep ids unique
     s = back.begin_attempt(1, 0.4, site="gold")
     assert s.span_id not in {x.span_id for x in tr.spans}
+
+
+def test_jsonl_round_trips_step_spans(tmp_path):
+    tr = _hand_steps(_hand_trace())
+    path = tmp_path / "t.jsonl"
+    write_jsonl(tr, str(path))
+    back = load_jsonl(str(path))
+    assert back.step_spans == tr.step_spans
+    assert len(back.spans) == len(tr.spans)
+
+
+def test_chrome_trace_puts_step_spans_on_one_engine_track_per_site():
+    tr = _hand_steps(_hand_trace())
+    evs = to_chrome_trace(tr)["traceEvents"]
+    pid = {e["args"]["name"]: e["pid"] for e in evs
+           if e["ph"] == "M" and e["name"] == "process_name"}
+    names = [e for e in evs if e["ph"] == "M" and e["name"] == "thread_name"]
+    assert {(e["pid"], e["tid"], e["args"]["name"]) for e in names} == {
+        (pid["eco"], ENGINE_TID, "engine"), (pid["gold"], ENGINE_TID,
+                                             "engine")}
+    eng = [e for e in evs if e.get("cat") == "engine"]
+    assert [(e["ph"], e["name"], e["tid"]) for e in eng] == [
+        ("X", "engine.step", ENGINE_TID), ("X", "engine.chunk", ENGINE_TID)
+    ] * 2
+    assert [e["pid"] for e in eng] == [pid["eco"]] * 2 + [pid["gold"]] * 2
+    step = eng[0]
+    assert step["ts"] == pytest.approx(0.1e6)
+    assert step["dur"] == pytest.approx(0.1e6)
+    assert eng[1]["args"]["lanes"] == [[0, 4]]
+    # the request spans keep their own tracks
+    assert len([e for e in evs if e["ph"] == "X"]) == 2 + 4
 
 
 def test_chrome_trace_structure(tmp_path):
@@ -472,6 +555,92 @@ def test_disabled_tracing_leaves_no_spans_and_identical_outputs(dense):
             assert "occupancy" in tr.metrics
             assert "bucket_hit" in tr.metrics
     assert out["on"] == out["off"]      # tracing never perturbs outputs
+
+
+ENGINE_SPANS = {"engine.step", "engine.seat", "engine.chunk",
+                "engine.dispatch", "engine.sync", "engine.commit"}
+
+
+def _inside(child, parent):
+    return parent.start_s <= child.start_s <= child.end_s <= parent.end_s
+
+
+@pytest.mark.parametrize("chunk", [None, 4])
+def test_engine_step_spans_cover_each_phase(dense, chunk):
+    """Every step() is one closed ``engine.step`` holding its phases; each
+    host sync is an ``engine.sync`` inside the phase that waited; the
+    spans' attrs count what the step did."""
+    cfg, model, params = dense
+    clock = FakeClock()
+    tracer = Tracer()
+    srv = BatchedServer(model, params, slots=2, max_len=MAX_LEN,
+                        dispatch_tokens=3, clock=clock, tracer=tracer,
+                        prefill_chunk=chunk)
+    reqs = _requests(cfg, n=3, new_tokens=5)
+    for r in reqs:
+        srv.submit(r)
+    steps = 0
+    while not srv.idle():
+        clock.t += TICK
+        srv.step(3)
+        steps += 1
+    spans = tracer.step_spans
+    by_id = {s.span_id: s for s in spans}
+    assert {s.name for s in spans} <= ENGINE_SPANS
+    top = [s for s in spans if s.parent_id is None]
+    assert [s.name for s in top] == ["engine.step"] * steps
+    for s in spans:
+        assert s.end_s is not None and s.site == ""
+        if s.parent_id is not None:
+            parent = by_id[s.parent_id]
+            assert _inside(s, parent)
+            want = ({"engine.chunk", "engine.dispatch", "engine.seat"}
+                    if s.name == "engine.sync" else {"engine.step"})
+            assert parent.name in want, (s.name, parent.name)
+    seats = [s for s in spans if s.name == "engine.seat"]
+    assert len(seats) == steps
+    assert sum(s.attrs["seated"] for s in seats) == len(reqs)
+    syncs = [s for s in spans if s.name == "engine.sync"]
+    assert len(syncs) == srv.host_syncs
+    program = {None: "_admit_jit", 4: "_chunk_jit"}[chunk]
+    assert {s.attrs["program"] for s in syncs} == {program, "_dispatch_jit"}
+    commits = [s for s in spans if s.name == "engine.commit"]
+    disp = [s for s in spans if s.name == "engine.dispatch"]
+    assert len(commits) == len(disp) == srv.dispatches
+    # every output token but each request's first came out of a dispatch
+    assert sum(c.attrs["tokens"] for c in commits) == \
+        sum(len(r.output) - 1 for r in reqs)
+    for d in disp:
+        assert d.attrs["n"] == 3 and 1 <= len(d.attrs["lanes"]) <= 2
+    for s in top:
+        assert s.attrs["seated"] >= s.attrs["decode_lanes"]
+    if chunk:
+        chunks = [s for s in spans if s.name == "engine.chunk"]
+        assert sum(c for s in chunks for _, c in s.attrs["lanes"]) == \
+            sum(len(r.prompt) for r in reqs)
+        assert sum(s.attrs["finals"] for s in chunks) == len(reqs)
+        assert sum(s.attrs["prefill_lanes"] for s in top) == \
+            sum(len(s.attrs["lanes"]) for s in chunks)
+
+
+def test_cluster_step_spans_keep_dies_apart(dense):
+    cfg, model, params = dense
+    clock = SimClock()
+    router = ClusterRouter(model, params, _eco_gold_cluster(), slots=2,
+                           max_len=MAX_LEN, clock=clock, dispatch_tokens=3,
+                           prefill_chunk=8)
+    tracer = trace_cluster(router)
+    for r in _requests(cfg, n=4, new_tokens=4):
+        router.submit(r)
+    _drive(router, clock)
+    steps = [s for s in tracer.step_spans if s.name == "engine.step"]
+    assert {s.site for s in steps} == {"eco", "gold"}
+    by_id = {s.span_id: s for s in tracer.step_spans}
+    for s in tracer.step_spans:
+        root = s
+        while root.parent_id is not None:
+            root = by_id[root.parent_id]
+        assert root.name == "engine.step" and root.site == s.site
 
 
 def test_reject_and_expire_paths_close_the_root(dense):
