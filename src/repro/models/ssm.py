@@ -1,13 +1,21 @@
 """Selective state-space blocks: Mamba-1 (falcon-mamba) and Mamba-2 (zamba2).
 
-Training/prefill uses a chunked linear scan: sequential lax.scan over chunks
-(carrying the state) with an associative scan *inside* each chunk — the
-memory-realistic TPU mapping of the selective-scan recurrence (the full
-(B,S,d_inner,d_state) tensor is never live; only one chunk is).  Decode is a
-single O(1) state update.
+Training/prefill runs a sequential lax.scan over chunks of tokens, carrying
+the state, so the full (B,S,*state) tensor is never live; only one chunk's
+work is, and the checkpointed chunk body is recomputed in the backward pass.
+Inside a chunk the two versions differ, because their decays differ:
 
-Recurrence: h_t = a_t * h_{t-1} + b_t ; associative combine
-(aL,bL)∘(aR,bR) = (aL*aR, bL*aR + bR).
+* Mamba-1 decays per channel and state entry (A is (d_in, N)).  Its chunk
+  body expands the per-token (d_in, N) transitions and runs an associative
+  scan over them: h_t = a_t * h_{t-1} + b_t, combine
+  (aL,bL)∘(aR,bR) = (aL*aR, bL*aR + bR).
+* Mamba-2 decays by one scalar per head (A is (H,)) and shares B and C
+  across heads, so its chunk body is the chunked SSD form of Dao & Gu
+  (arXiv:2405.21060, section 6): small matmuls over the chunk's (Q x Q)
+  decay-masked C·Bᵀ and its inputs, never the per-token (H, P, N)
+  expansion.
+
+Decode is a single O(1) state update in both.
 """
 from __future__ import annotations
 
@@ -210,6 +218,60 @@ def mamba2_init(key, d_model: int, *, d_state: int, expand: int, conv: int,
     }
 
 
+def _ssd_scan(xh, Bm, Cm, dt, A, h0, chunk: int):
+    """Mamba-2's selective scan in chunked SSD (matmul) form.
+
+    xh: (B,S,H,P) inputs; Bm, Cm: (B,S,N); dt: (B,S,H) f32; A: (H,) f32
+    decay rates; h0: (B,H,P,N) f32.  Returns (y (B,S,H,P) f32 without the
+    D skip, h_last).  Within a chunk of Q tokens, with cum = cumsum(dt·A):
+      y_t   = Σ_{s<=t} exp(cum_t - cum_s)·(C_t·B_s)·dt_s·x_s
+              + exp(cum_t)·C_t·h_prev
+      h_new = exp(cum_Q)·h_prev + Σ_s exp(cum_Q - cum_s)·dt_s·x_s ⊗ B_s
+    The largest live tensor is the (B,H,Q,Q) decay mask.  Every chunk of a
+    call runs the same body on the same carry, so a prefill split at
+    multiples of ``chunk`` equals the unsplit one bit for bit.  The state
+    and every product stay float32 (``Precision.HIGHEST``: the TPU's
+    default would round operands to bfloat16)."""
+    B, S = dt.shape[:2]
+    chunk = min(chunk, S)
+    pad = (-S) % chunk
+    if pad:  # padded positions get dt = 0: decay 1, input 0
+        xh, Bm, Cm, dt = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (xh, Bm, Cm, dt))
+    n = (S + pad) // chunk
+    chunked = jax.tree.map(
+        lambda t: t.reshape((B, n, chunk) + t.shape[2:]).swapaxes(0, 1),
+        (xh, Bm, Cm, dt))
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    mm = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
+
+    @jax.checkpoint
+    def step(h, inputs):
+        # named scope -> HLO metadata for fused-kernel traffic attribution
+        with jax.named_scope("selective_scan_kernel"):
+            x, b, c, dt_k = jax.tree.map(
+                lambda t: t.astype(jnp.float32), inputs)
+            dt_h = dt_k.swapaxes(1, 2)  # (B,H,Q)
+            cum = jnp.cumsum(dt_h * A[:, None], axis=-1)
+            # exp of the masked (upper) half would overflow: mask first
+            decay = jnp.exp(jnp.where(
+                causal, cum[..., :, None] - cum[..., None, :], -jnp.inf))
+            cb = mm("bqn,bsn->bqs", c, b)
+            m = decay * cb[:, None] * dt_h[:, :, None, :]  # (B,H,Q,Q)
+            y = (mm("bhqs,bshp->bqhp", m, x)
+                 + mm("bqn,bhpn->bqhp", c, h)
+                 * jnp.exp(cum).swapaxes(1, 2)[..., None])
+            w = (jnp.exp(cum[..., -1:] - cum) * dt_h).swapaxes(1, 2)
+            h_new = (jnp.exp(cum[..., -1])[..., None, None] * h
+                     + mm("bshp,bsn->bhpn", x * w[..., None], b))
+            return h_new, y
+
+    h_last, y = lax.scan(step, h0, chunked)
+    y = y.swapaxes(0, 1).reshape((B, n * chunk) + y.shape[3:])
+    return y[:, :S], h_last
+
+
 def mamba2_apply(p, x, *, d_state: int, head_dim: int, chunk: int = 64,
                  state: Tuple | None = None, return_state: bool = False):
     B, S, _ = x.shape
@@ -244,14 +306,9 @@ def mamba2_apply(p, x, *, d_state: int, head_dim: int, chunk: int = 64,
         y = jnp.einsum("bhpn,bn->bhp", h_last, Cm[:, 0])[:, None]
         xh = xh1
     else:
-        def expand(inputs):
-            xc_k, dt_k = inputs
-            _, a, bx, Cm = parts(xc_k, dt_k)
-            return a, bx, (lambda h_seq:
-                           jnp.einsum("bshpn,bsn->bshp", h_seq, Cm))
-
-        y, h_last = _chunked_ssm((xc_all, dt), h0, expand, chunk)
         xh = xc_all[..., :d_in].reshape(B, S, H, head_dim)
+        y, h_last = _ssd_scan(xh, xc_all[..., d_in:d_in + d_state],
+                              xc_all[..., d_in + d_state:], dt, A, h0, chunk)
     y = y + p["D"][:, None] * xh.astype(jnp.float32)
     y = y.reshape(B, S, d_in).astype(x.dtype)
     y = rmsnorm(p["norm"], y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype))

@@ -1,12 +1,14 @@
 """Fused-kernel traffic model: the TPU-target memory term.
 
 The dry-run compiles through XLA:CPU, which materializes the blockwise
-attention probabilities and the selective-scan state expansion to HBM-visible
-buffers.  On the TPU target those live in VMEM inside fused Pallas kernels
-(we ship the kernel-granularity implementations: flash_vjp.py's blockwise
-algorithm IS the Pallas flash kernel schedule, and the fma_emu kernel
-demonstrates the pallas_call machinery; the SSM scan follows the official
-Pallas mamba kernels' chunking).
+attention probabilities and the selective-scan intermediates to HBM-visible
+buffers.  This module models a TPU target on which those live in VMEM
+inside fused Pallas kernels (flash_vjp.py's blockwise algorithm is the
+Pallas flash kernel schedule).  The serving and training paths have no such
+SSM kernel: they run the jnp chunk bodies of models/ssm.py (Mamba-2 in
+chunked SSD matmul form, Mamba-1 as an associative scan over each chunk's
+state expansion), so on the chip the scan's traffic is what XLA makes of
+those bodies, not this model's interface estimate.
 
 This module recomputes the memory roofline term under that model:
   * traffic attributed (via jax.named_scope -> HLO metadata op_name) to

@@ -74,13 +74,76 @@ def test_mamba_streaming_equals_full(version):
     assert float(jnp.abs(tail - full[:, 7:]).max()) < 1e-4
 
 
-def test_mamba_gradients_flow():
-    p = mamba1_init(jax.random.key(1), 8, d_state=4, expand=2, conv=4,
-                    dtype=jnp.float32)
+def _decaying_mamba2(key, d, *, d_state, head_dim):
+    """f32 Mamba-2 params whose heads decay at different, non-trivial
+    rates (the init's A = -1, dt ~ 0.01 barely decays)."""
+    p = mamba2_init(key, d, d_state=d_state, expand=2, conv=4,
+                    head_dim=head_dim, dtype=jnp.float32)
+    H = p["A_log"].shape[0]
+    p["A_log"] = jnp.log(jnp.linspace(0.5, 4.0, H, dtype=jnp.float32))
+    p["dt_bias"] = jnp.linspace(-3.0, 0.5, H, dtype=jnp.float32)
+    return p
+
+
+@pytest.mark.parametrize("S,chunk", [(8, 4), (13, 4), (16, 8), (13, 8),
+                                     (5, 8)])
+def test_mamba2_ssd_prefill_equals_recurrence(S, chunk):
+    """The chunked SSD prefill equals the per-token recurrence (a loop of
+    S == 1 calls) from a non-zero state, whole and partial chunks."""
+    rng = np.random.default_rng(6)
+    d, N, P = 16, 4, 8
+    p = _decaying_mamba2(jax.random.key(5), d, d_state=N, head_dim=P)
+    H = 2 * d // P
+    x = jnp.asarray(rng.standard_normal((2, S, d)), jnp.float32)
+    st0 = (jnp.asarray(rng.standard_normal((2, 3, 2 * d + 2 * N)),
+                       jnp.float32),
+           jnp.asarray(rng.standard_normal((2, H, P, N)), jnp.float32))
+    apply = lambda x, st: mamba2_apply(p, x, d_state=N, head_dim=P,
+                                       chunk=chunk, state=st,
+                                       return_state=True)
+    y, (conv, h) = apply(x, st0)
+    outs, st = [], st0
+    for t in range(S):
+        y_t, st = apply(x[:, t:t + 1], st)
+        outs.append(y_t)
+    ref = jnp.concatenate(outs, 1)
+    scale = float(jnp.abs(ref).max())
+    assert float(jnp.abs(y - ref).max()) <= 1e-5 * scale
+    assert float(jnp.abs(h - st[1]).max()) <= 1e-5 * float(
+        jnp.abs(st[1]).max())
+    assert np.array_equal(np.asarray(conv), np.asarray(st[0]))
+
+
+def test_mamba2_prefill_has_no_state_expansion():
+    """No intermediate of the compiled Mamba-2 prefill holds a
+    (chunk, H, P, N) per-token state expansion: the SSD body works on
+    (Q x Q) and (P x N) matmuls."""
+    d, N, P, chunk = 12, 5, 6, 8
+    p = mamba2_init(jax.random.key(6), d, d_state=N, expand=2, conv=4,
+                    head_dim=P, dtype=jnp.float32)
+    H = 2 * d // P
+    x = jnp.ones((2, 3 * chunk, d), jnp.float32)
+    hlo = jax.jit(lambda p, x: mamba2_apply(
+        p, x, d_state=N, head_dim=P, chunk=chunk)).lower(p, x).compile(
+        ).as_text()
+    assert "selective_scan_kernel" in hlo
+    assert f"{chunk},{H},{P},{N}]" not in hlo
+
+
+@pytest.mark.parametrize("version", [1, 2])
+def test_mamba_gradients_flow(version):
+    if version == 1:
+        p = mamba1_init(jax.random.key(1), 8, d_state=4, expand=2, conv=4,
+                        dtype=jnp.float32)
+        apply = lambda p, x: mamba1_apply(p, x, d_state=4, chunk=4)
+    else:
+        p = _decaying_mamba2(jax.random.key(1), 8, d_state=4, head_dim=4)
+        apply = lambda p, x: mamba2_apply(p, x, d_state=4, head_dim=4,
+                                          chunk=4)
     x = jnp.ones((1, 16, 8), jnp.float32)
 
     def loss(p):
-        return jnp.sum(mamba1_apply(p, x, d_state=4, chunk=4) ** 2)
+        return jnp.sum(apply(p, x) ** 2)
 
     g = jax.grad(loss)(p)
     norms = [float(jnp.abs(v).sum()) for v in jax.tree.leaves(g)]
