@@ -32,12 +32,16 @@ def rmsnorm_init(d: int, dtype):
     return {"scale": jnp.ones((d,), dtype)}
 
 
-def rmsnorm(params, x, eps: float = 1e-6):
-    dt = x.dtype
+def unit_rms(x, eps: float = 1e-6):
+    """x in float32, scaled to unit RMS over its last axis."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    y = xf * jax.lax.rsqrt(var + eps)
-    return (y * params["scale"].astype(jnp.float32)).astype(dt)
+    return xf * jax.lax.rsqrt(var + eps)
+
+
+def rmsnorm(params, x, eps: float = 1e-6):
+    y = unit_rms(x, eps)
+    return (y * params["scale"].astype(jnp.float32)).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,11 @@ Inside a chunk the two versions differ, because their decays differ:
 * Mamba-1 decays per channel and state entry (A is (d_in, N)).  Its chunk
   body expands the per-token (d_in, N) transitions and runs an associative
   scan over them: h_t = a_t * h_{t-1} + b_t, combine
-  (aL,bL)∘(aR,bR) = (aL*aR, bL*aR + bR).
+  (aL,bL)∘(aR,bR) = (aL*aR, bL*aR + bR).  The mixer is Falcon-Mamba's
+  (arXiv:2410.05355), the only Mamba-1 model registered: after ``x_proj``
+  Δ_low (before ``dt_proj``), B and C are each scaled to unit RMS over
+  their last axis by a weightless norm (``MIXER_RMS_EPS``), in float32 and
+  cast back, alike in the chunk body and the one-token decode update.
 * Mamba-2 decays by one scalar per head (A is (H,)) and shares B and C
   across heads, so its chunk body is the chunked SSD form of Dao & Gu
   (arXiv:2405.21060, section 6): small matmuls over the chunk's (Q x Q)
@@ -26,7 +30,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models.layers import dense_init, rmsnorm, rmsnorm_init
+from repro.models.layers import dense_init, rmsnorm, rmsnorm_init, unit_rms
+
+
+#: Falcon-Mamba's ``mixer_rms_eps``: epsilon of the Δ/B/C norms.
+MIXER_RMS_EPS = 1e-6
 
 
 def _combine(left, right):
@@ -110,6 +118,8 @@ def _mamba1_core(p, xc, d_state: int):
     dt_rank = p["dt_proj"].shape[0]
     proj = xc @ p["x_proj"]
     dt_low, Bm, Cm = jnp.split(proj, [dt_rank, dt_rank + d_state], axis=-1)
+    dt_low, Bm, Cm = (unit_rms(t, MIXER_RMS_EPS).astype(t.dtype)
+                      for t in (dt_low, Bm, Cm))
     dt = jax.nn.softplus((dt_low @ p["dt_proj"]).astype(jnp.float32)
                          + p["dt_bias"])  # (B,S,d_in)
     A = -jnp.exp(p["A_log"])  # (d_in, n)

@@ -4,8 +4,9 @@ The TPU compiler is installed even where no TPU is attached, and it compiles
 for a described ``v5e:2x2`` topology.  These tests compile each Pallas
 kernel at zamba2-1.2b widths, asserting that the program holds the Mosaic
 kernel (``tpu_custom_call``), and the serving engine's decode and
-prefill-chunk programs for zamba2-1.2b at its published config, asserting
-that they fit the chip's 16 GB.  They catch what interpret mode cannot:
+prefill-chunk programs for zamba2-1.2b at its published config and for one
+16-layer stage of falcon-mamba-7b at its published widths, asserting that
+they fit the chip's 16 GB.  They catch what interpret mode cannot:
 tiling and VMEM refusals, unsupported ops, programs that do not fit.
 
 The topology is described inside a fixture, never at import: only one
@@ -96,16 +97,10 @@ def _fits(compiled):
     return need
 
 
-@pytest.fixture(scope="module")
-def zamba2(one_chip):
-    """zamba2-1.2b at its published config: params, an 8-slot 1024-token
-    decode cache, and the slot state, all as shapes on one chip."""
-    from repro.configs.base import get_config
-    from repro.models import LM
+def _served(model, one_chip, slots, max_len):
+    """Params, a ``slots``-slot decode cache of ``max_len`` and the slot
+    state of ``model``, all as shapes on one chip."""
     from repro.models.model import DecodeCache
-
-    model = LM(get_config("zamba2-1.2b"))
-    slots, max_len = 8, 1024
 
     def state():
         cache = model.init_cache(slots, max_len)
@@ -121,20 +116,57 @@ def zamba2(one_chip):
     return model, params, on_chip(jax.eval_shape(state)), slots
 
 
-def test_zamba2_decode_scan_fits_v5e(zamba2):
+@pytest.fixture(scope="module")
+def zamba2(one_chip):
+    """zamba2-1.2b at its published config: params, an 8-slot 1024-token
+    decode cache, and the slot state, all as shapes on one chip."""
+    from repro.configs.base import get_config
+    from repro.models import LM
+    return _served(LM(get_config("zamba2-1.2b")), one_chip, 8, 1024)
+
+
+@pytest.fixture(scope="module")
+def falcon_stage(one_chip):
+    """falcon-mamba-7b cut to one 16-layer pipeline stage at every
+    published width (the benchmark's configuration), 8 slots."""
+    import dataclasses
+
+    from repro.configs.base import get_config
+    from repro.models import LM
+    cfg = dataclasses.replace(get_config("falcon-mamba-7b"), n_layers=16)
+    return _served(LM(cfg), one_chip, 8, 4352)
+
+
+def _decode_scan_fits(served):
     from repro.serve.engine import _dispatch_jit
-    model, params, (cache, tok, active, budget), _ = zamba2
+    model, params, (cache, tok, active, budget), _ = served
     compiled = _dispatch_jit.lower(model, 0, 8, (), params, cache, tok,
                                    active, budget).compile()
     _fits(compiled)
 
 
-def test_zamba2_prefill_chunk_fits_v5e(zamba2, one_chip):
+def _prefill_chunk_fits(served, one_chip):
     from repro.serve.engine import _chunk_jit
-    model, params, (cache, tok, active, budget), slots = zamba2
+    model, params, (cache, tok, active, budget), slots = served
     lanes, chunk = slots, 256
     tokens = _shapes(one_chip, (lanes, chunk), dtype=jnp.int32)[0]
     per_lane = _shapes(one_chip, *[(lanes,)] * 5, dtype=jnp.int32)
     compiled = _chunk_jit.lower(model, params, cache, tok, active, budget,
                                 tokens, *per_lane).compile()
     _fits(compiled)
+
+
+def test_zamba2_decode_scan_fits_v5e(zamba2):
+    _decode_scan_fits(zamba2)
+
+
+def test_zamba2_prefill_chunk_fits_v5e(zamba2, one_chip):
+    _prefill_chunk_fits(zamba2, one_chip)
+
+
+def test_falcon_stage_decode_scan_fits_v5e(falcon_stage):
+    _decode_scan_fits(falcon_stage)
+
+
+def test_falcon_stage_prefill_chunk_fits_v5e(falcon_stage, one_chip):
+    _prefill_chunk_fits(falcon_stage, one_chip)

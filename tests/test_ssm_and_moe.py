@@ -130,11 +130,76 @@ def test_mamba2_prefill_has_no_state_expansion():
     assert f"{chunk},{H},{P},{N}]" not in hlo
 
 
+def _falcon_mamba1(key, d, *, d_state):
+    """f32 Mamba-1 params whose channels step at different rates (the
+    init's dt ~ 0.01 barely decays)."""
+    p = mamba1_init(key, d, d_state=d_state, expand=2, conv=4,
+                    dtype=jnp.float32)
+    p["dt_bias"] = jnp.linspace(-3.0, 0.5, 2 * d, dtype=jnp.float32)
+    return p
+
+
+@pytest.mark.parametrize("S,chunk", [(8, 4), (13, 4), (16, 8), (13, 8),
+                                     (5, 8)])
+def test_mamba1_normed_prefill_equals_recurrence(S, chunk):
+    """With Falcon-Mamba's Δ/B/C norms, the chunked prefill equals a
+    loop of S == 1 calls from a non-zero state, for y, h and the conv
+    carry, over whole and partial chunks."""
+    rng = np.random.default_rng(7)
+    d, N = 16, 4
+    p = _falcon_mamba1(jax.random.key(8), d, d_state=N)
+    x = jnp.asarray(rng.standard_normal((2, S, d)), jnp.float32)
+    st0 = (jnp.asarray(rng.standard_normal((2, 3, 2 * d)), jnp.float32),
+           jnp.asarray(rng.standard_normal((2, 2 * d, N)), jnp.float32))
+    apply = lambda x, st: mamba1_apply(p, x, d_state=N, chunk=chunk,
+                                       state=st, return_state=True)
+    y, (conv, h) = apply(x, st0)
+    outs, st = [], st0
+    for t in range(S):
+        y_t, st = apply(x[:, t:t + 1], st)
+        outs.append(y_t)
+    ref = jnp.concatenate(outs, 1)
+    # f32 throughout; the associative scan multiplies the decays in
+    # another order than the loop, a few ulps apart
+    scale = float(jnp.abs(ref).max())
+    assert float(jnp.abs(y - ref).max()) <= 1e-5 * scale
+    assert float(jnp.abs(h - st[1]).max()) <= 1e-5 * float(
+        jnp.abs(st[1]).max())
+    assert np.array_equal(np.asarray(conv), np.asarray(st[0]))
+    # Δ_low, B and C are normed: scaling x_proj, which makes all three,
+    # moves y only through the norms' epsilon, at most 1.3 % of its scale
+    # here (Δ_low is d // 16 = 1 wide, so x·rsqrt(x² + eps) feels eps
+    # where x is near 0); without the norms y moves 5-20 times its scale
+    big = dict(p, x_proj=7.0 * p["x_proj"])
+    y7 = mamba1_apply(big, x, d_state=N, chunk=chunk, state=st0)
+    assert float(jnp.abs(y7 - y).max()) <= 0.05 * scale
+
+
+def test_mamba1_normed_chunk_resume_is_bitwise():
+    """A Mamba-1 prefill with the Δ/B/C norms split at a multiple of the scan chunk,
+    the state carried between the calls, equals the unsplit prefill bit
+    for bit (chunked serving prefill relies on it)."""
+    rng = np.random.default_rng(9)
+    d, N, chunk = 16, 4, 4
+    p = _falcon_mamba1(jax.random.key(10), d, d_state=N)
+    x = jnp.asarray(rng.standard_normal((2, 20, d)), jnp.float32)
+    apply = jax.jit(lambda x, st: mamba1_apply(
+        p, x, d_state=N, chunk=chunk, state=st, return_state=True))
+    st0 = (jnp.zeros((2, 3, 2 * d), jnp.float32),
+           jnp.zeros((2, 2 * d, N), jnp.float32))
+    y, (conv, h) = apply(x, st0)
+    y1, st1 = apply(x[:, :8], st0)
+    y2, (conv2, h2) = apply(x[:, 8:], st1)
+    assert np.array_equal(np.asarray(jnp.concatenate([y1, y2], 1)),
+                          np.asarray(y))
+    assert np.array_equal(np.asarray(h2), np.asarray(h))
+    assert np.array_equal(np.asarray(conv2), np.asarray(conv))
+
+
 @pytest.mark.parametrize("version", [1, 2])
 def test_mamba_gradients_flow(version):
-    if version == 1:
-        p = mamba1_init(jax.random.key(1), 8, d_state=4, expand=2, conv=4,
-                        dtype=jnp.float32)
+    if version == 1:  # through Falcon-Mamba's Δ/B/C norms
+        p = _falcon_mamba1(jax.random.key(1), 8, d_state=4)
         apply = lambda p, x: mamba1_apply(p, x, d_state=4, chunk=4)
     else:
         p = _decaying_mamba2(jax.random.key(1), 8, d_state=4, head_dim=4)
