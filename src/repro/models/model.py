@@ -135,37 +135,53 @@ def attn_block_apply(p, x, positions, cfg: ArchConfig, *, policy=None,
     return out, aux
 
 
-def attn_block_decode(p, x, k_cache, v_cache, cache_len, cfg: ArchConfig, *,
-                      ring: bool = False, policy=None):
-    """x: (B,1,d); caches (B,Smax,Hkv,D); cache_len: current count (before
-    this token).  Returns (out, new_k, new_v)."""
+def _write_rows(cache, rows, index, pos, writes):
+    """Write ``rows[b]`` (B,Hkv,D) at ``cache[index, b, pos[b]]`` for the
+    lanes where ``writes[b]``; every other lane's row is read and written
+    back as it was (a position past the end clamps to the last row in
+    both).  One ``dynamic_update_slice`` per lane, not a scatter: XLA
+    updates it in place in the cache's stored layout (positions minor),
+    where a scatter has the whole cache relaid out, and padded, around the
+    scan."""
+    for b in range(rows.shape[0]):
+        at = (index, b, pos[b], 0, 0)
+        old = lax.dynamic_slice(cache, at, (1, 1, 1) + rows.shape[1:])
+        new = rows[b].astype(cache.dtype)[None, None, None]
+        cache = lax.dynamic_update_slice(cache, jnp.where(writes[b], new, old),
+                                         at)
+    return cache
+
+
+def attn_block_decode(p, x, k_all, v_all, index, cache_len, cfg: ArchConfig,
+                      *, ring: bool = False, policy=None, active=None):
+    """x: (B,1,d); k_all/v_all: (N,B,Smax,Hkv,D) stacked caches, of which
+    this block reads and writes entry ``index`` in place; cache_len: ()
+    or (B,) count before this token; active: (B,) bool or None (every
+    lane).  A lane that is not active writes its old K/V row back, so its
+    cache bits stay verbatim.
+    Returns (out, new_k_all, new_v_all)."""
     B = x.shape[0]
-    Smax = k_cache.shape[1]
+    Smax = k_all.shape[2]
     h = rmsnorm(p["ln1"], x)
     cl = jnp.asarray(cache_len)
     per_batch = cl.ndim == 1  # continuous batching: each slot has its own len
     positions = (cl if per_batch else jnp.full((B,), cl))[:, None]
     q, k, v = _qkv(p, h, cfg, positions, policy)
-    write_idx = (cl % Smax) if ring else cl
-    if per_batch:
-        k_cache = k_cache.at[jnp.arange(B), write_idx].set(
-            k[:, 0].astype(k_cache.dtype))
-        v_cache = v_cache.at[jnp.arange(B), write_idx].set(
-            v[:, 0].astype(v_cache.dtype))
-    else:
-        k_cache = lax.dynamic_update_slice_in_dim(
-            k_cache, k.astype(k_cache.dtype), write_idx, axis=1)
-        v_cache = lax.dynamic_update_slice_in_dim(
-            v_cache, v.astype(v_cache.dtype), write_idx, axis=1)
+    write_idx = jnp.broadcast_to((cl % Smax) if ring else cl, (B,))
+    writes = write_idx < Smax  # a linear cache drops writes past its end
+    if active is not None:
+        writes = writes & active
+    k_all = _write_rows(k_all, k[:, 0], index, write_idx, writes)
+    v_all = _write_rows(v_all, v[:, 0], index, write_idx, writes)
     valid = jnp.minimum(cl + 1, Smax)
     # window semantics: a ring cache IS the window (attention is permutation
     # invariant over KV), so no extra window mask is needed when ring=True.
-    attn = decode_attention(q, k_cache, v_cache, valid,
+    attn = decode_attention(q, k_all[index], v_all[index], valid,
                             window=0 if ring else cfg.window)
     x = x + matmul(attn.reshape(B, 1, -1), p["wo"], policy)
     h2 = rmsnorm(p["ln2"], x)
     ff, _ = _ffn(p, h2, cfg, policy)
-    return x + ff, k_cache, v_cache
+    return x + ff, k_all, v_all
 
 
 def _chunk_attn_block(p, x, k_cache, v_cache, offsets, chunk_lens, positions,
@@ -482,72 +498,65 @@ class LM:
         return DecodeCache(cache.data, jnp.int32(length))
 
     # -------------------------------------------------------- decode -------
-    def decode_step(self, params, cache: DecodeCache, tokens, *, policy=None):
-        """tokens: (B,1) -> (logits (B,1,V), new cache)."""
+    def decode_step(self, params, cache: DecodeCache, tokens, *, policy=None,
+                    active=None):
+        """tokens: (B,1) -> (logits (B,1,V), new cache).
+
+        Weights, SSM state and K/V are read where they lie in their stacks
+        and written back row by row, so a fused multi-token scan carries
+        the cache in place.  active: (B,) bool or None (every lane); a lane
+        that is not active changes nothing: its K/V row is written back as
+        it was and its conv/h keep their old value inside each layer's own
+        update.  The cache length still advances for every lane
+        (``decode_scan`` freezes it)."""
         cfg = self.cfg
         x = embed_apply(params["embed"], tokens)
         x = shard(x, "batch", None, None)
-        L = cfg.n_layers
-        ring = bool(cfg.window)
         clen = cache.length
         data = dict(cache.data)
 
         if cfg.family in ("dense", "moe", "vlm", "audio"):
+            ring = bool(cfg.window)
 
-            def body(x, inp):
-                lp, kc, vc = inp
-                y, kc2, vc2 = attn_block_decode(lp, x, kc, vc, clen, cfg,
-                                                ring=ring, policy=policy)
-                return y, (kc2, vc2)
+            def body(carry, inp):
+                x, k, v = carry
+                lp, i = inp
+                y, k, v = attn_block_decode(lp, x, k, v, i, clen, cfg,
+                                            ring=ring, policy=policy,
+                                            active=active)
+                return (y, k, v), None
 
-            x, (k2, v2) = lax.scan(body, x,
-                                   (params["layers"], data["k"], data["v"]))
-            data["k"], data["v"] = k2, v2
-        elif cfg.family == "ssm":
+            (x, data["k"], data["v"]), _ = lax.scan(
+                body, (x, data["k"], data["v"]),
+                (params["layers"], jnp.arange(cfg.n_layers)))
+        else:  # ssm, hybrid: Mamba layers, the shared block between segments
+            def lanes(new, old):
+                if active is None:
+                    return new
+                return jnp.where(
+                    active.reshape(active.shape + (1,) * (new.ndim - 1)),
+                    new, old)
 
-            def body(x, inp):
-                lp, conv, h = inp
-                y, (conv2, h2) = ssm_block_apply(lp, x, cfg,
-                                                 state=(conv, h),
-                                                 return_state=True)
-                return y, (conv2, h2)
+            def mamba_layer(carry, i):
+                x, conv, h = carry
+                lp = jax.tree.map(lambda a: a[i], params["layers"])
+                y, (c2, h2) = ssm_block_apply(lp, x, cfg,
+                                              state=(conv[i], h[i]),
+                                              return_state=True)
+                return (y, conv.at[i].set(lanes(c2, conv[i])),
+                        h.at[i].set(lanes(h2, h[i]))), None
 
-            x, (c2, h2) = lax.scan(body, x,
-                                   (params["layers"], data["conv"], data["h"]))
-            data["conv"], data["h"] = c2, h2
-        else:  # hybrid
-            new_conv, new_h = [], []
-            app_idx = 0
-            k_apps, v_apps = [], []
+            conv, h = data["conv"], data["h"]
+            app = 0
             for (s, e, shared) in self._segments():
-                seg = jax.tree.map(lambda a: a[s:e], params["layers"])
-                conv_seg = data["conv"][s:e]
-                h_seg = data["h"][s:e]
-
-                def body(x, inp):
-                    lp, conv, h = inp
-                    y, (conv2, h2) = ssm_block_apply(lp, x, cfg,
-                                                     state=(conv, h),
-                                                     return_state=True)
-                    return y, (conv2, h2)
-
-                x, (c2, h2) = lax.scan(body, x, (seg, conv_seg, h_seg))
-                new_conv.append(c2)
-                new_h.append(h2)
+                (x, conv, h), _ = lax.scan(mamba_layer, (x, conv, h),
+                                           jnp.arange(s, e))
                 if shared:
-                    y, kc2, vc2 = attn_block_decode(
-                        params["shared_attn"], x, data["k"][app_idx],
-                        data["v"][app_idx], clen, cfg, ring=False,
-                        policy=policy)
-                    x = y
-                    k_apps.append(kc2)
-                    v_apps.append(vc2)
-                    app_idx += 1
-            data["conv"] = jnp.concatenate(new_conv, 0)
-            data["h"] = jnp.concatenate(new_h, 0)
-            if k_apps:
-                data["k"] = jnp.stack(k_apps, 0)
-                data["v"] = jnp.stack(v_apps, 0)
+                    x, data["k"], data["v"] = attn_block_decode(
+                        params["shared_attn"], x, data["k"], data["v"], app,
+                        clen, cfg, ring=False, policy=policy, active=active)
+                    app += 1
+            data["conv"], data["h"] = conv, h
 
         x = rmsnorm(params["final_norm"], x)
         logits = unembed_apply(params["embed"], x, policy)
@@ -771,14 +780,20 @@ class LM:
         cache.length must be per-slot (B,); tok: (B, 1) next token per slot;
         active: (B,) bool gates which lanes sample/advance; budget: (B,)
         int32 remaining tokens per slot.  Inactive lanes still ride the
-        batched step (wasted lanes, the continuous-batching deal) but their
-        length/token/budget are frozen, so their cache writes land beyond
-        their valid length and stay masked.  Lanes deactivate *on device*
-        when their budget hits zero — or, with ``stop_tokens`` (a static
-        tuple of EOS-class token ids), when they sample a stop token: the
-        stop token itself is still emitted (and counted against the
-        budget), then the lane freezes inside the same dispatch, so no
-        post-EOS tokens are ever decoded or charged.  Returns
+        batched step (wasted lanes, the continuous-batching deal), but
+        nothing they compute lands: they are frozen at the write, not by a
+        select over the whole cache.  ``decode_step`` writes an inactive
+        lane's K/V row back as it was and keeps its conv/h inside each
+        layer's update, and its length/token/budget are frozen.  So an
+        inactive lane's cache bits stay verbatim: a lane mid chunked
+        prefill keeps the partial KV, conv and h its chunks wrote, ring
+        caches keep their history whole, and the next chunk resumes from
+        exactly that state.  Lanes deactivate *on device* when their
+        budget hits zero — or, with ``stop_tokens`` (a static tuple of
+        EOS-class token ids), when they sample a stop token: the stop token
+        itself is still emitted (and counted against the budget), then the
+        lane freezes inside the same dispatch, so no post-EOS tokens are
+        ever decoded or charged.  Returns
         ``(cache, tok, active, budget, toks (n, B), emitted (n, B))`` where
         ``emitted[t, b]`` marks lane b having sampled ``toks[t, b]`` at
         scan step t.
@@ -788,29 +803,19 @@ class LM:
         def body(carry, _):
             cache, tok, active, budget = carry
             logits, stepped = self.decode_step(params, cache, tok,
-                                               policy=policy)
+                                               policy=policy, active=active)
             nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
             emit = jnp.where(active, nxt, jnp.int32(pad_id))
             budget = budget - active.astype(budget.dtype)
             length = jnp.where(active, stepped.length, cache.length)
             new_tok = jnp.where(active[:, None], nxt[:, None], tok)
-            # inactive lanes keep their cache bits verbatim: a lane mid
-            # chunked-prefill holds live partial KV/conv/h state that the
-            # batched decode step would otherwise clobber (SSM state and
-            # ring writes are not masked by length the way linear KV
-            # writes are); active lanes take the stepped data bitwise
-            new_data = jax.tree.map(
-                lambda n, o: jnp.where(
-                    active.reshape((1, active.shape[0])
-                                   + (1,) * (n.ndim - 2)), n, o),
-                stepped.data, cache.data)
             new_active = active & (budget > 0)
             if stop_tokens:
                 stopped = jnp.zeros_like(active)
                 for s in stop_tokens:
                     stopped = stopped | (nxt == jnp.int32(s))
                 new_active = new_active & ~(active & stopped)
-            return (DecodeCache(new_data, length), new_tok, new_active,
+            return (DecodeCache(stepped.data, length), new_tok, new_active,
                     budget), (emit, active)
 
         (cache, tok, active, budget), (toks, emitted) = lax.scan(

@@ -6,8 +6,10 @@ kernel at zamba2-1.2b widths, asserting that the program holds the Mosaic
 kernel (``tpu_custom_call``), and the serving engine's decode and
 prefill-chunk programs for zamba2-1.2b at its published config and for one
 16-layer stage of falcon-mamba-7b at its published widths, asserting that
-they fit the chip's 16 GB.  They catch what interpret mode cannot:
-tiling and VMEM refusals, unsupported ops, programs that do not fit.
+they fit the chip's 16 GB, and that zamba2's decode dispatch carries its
+cache in place (temporaries below the K/V cache).  They catch what
+interpret mode cannot: tiling and VMEM refusals, unsupported ops,
+programs that do not fit, copies the compiler adds.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -137,12 +139,16 @@ def falcon_stage(one_chip):
     return _served(LM(cfg), one_chip, 8, 4352)
 
 
-def _decode_scan_fits(served):
+def _decode_scan(served):
     from repro.serve.engine import _dispatch_jit
     model, params, (cache, tok, active, budget), _ = served
-    compiled = _dispatch_jit.lower(model, 0, 8, (), params, cache, tok,
-                                   active, budget).compile()
-    _fits(compiled)
+    return _dispatch_jit.lower(model, 0, 8, (), params, cache, tok, active,
+                               budget).compile()
+
+
+@pytest.fixture(scope="module")
+def zamba2_decode_scan(zamba2):
+    return _decode_scan(zamba2)
 
 
 def _prefill_chunk_fits(served, one_chip):
@@ -156,8 +162,20 @@ def _prefill_chunk_fits(served, one_chip):
     _fits(compiled)
 
 
-def test_zamba2_decode_scan_fits_v5e(zamba2):
-    _decode_scan_fits(zamba2)
+def test_zamba2_decode_scan_fits_v5e(zamba2_decode_scan):
+    _fits(zamba2_decode_scan)
+
+
+def test_zamba2_decode_scan_temporaries_below_kv_cache(zamba2,
+                                                      zamba2_decode_scan):
+    """The dispatch updates its carried cache in place: its temporaries
+    stay below the K/V cache it carries (a whole-cache select, a restack or
+    a relayout of the cache around the token scan each take more)."""
+    _, _, (cache, _, _, _), _ = zamba2
+    kv = sum(cache.data[n].size * cache.data[n].dtype.itemsize
+             for n in ("k", "v"))
+    temp = zamba2_decode_scan.memory_analysis().temp_size_in_bytes
+    assert temp < kv, (temp, kv)
 
 
 def test_zamba2_prefill_chunk_fits_v5e(zamba2, one_chip):
@@ -165,7 +183,7 @@ def test_zamba2_prefill_chunk_fits_v5e(zamba2, one_chip):
 
 
 def test_falcon_stage_decode_scan_fits_v5e(falcon_stage):
-    _decode_scan_fits(falcon_stage)
+    _fits(_decode_scan(falcon_stage))
 
 
 def test_falcon_stage_prefill_chunk_fits_v5e(falcon_stage, one_chip):

@@ -21,6 +21,7 @@ import pytest
 
 from repro.configs.base import get_config
 from repro.models import LM
+from repro.models.model import DecodeCache
 from repro.serve.engine import BatchedServer, Request, greedy_decode
 
 from helpers import FakeClock
@@ -45,6 +46,11 @@ def dense():
 @pytest.fixture(scope="module")
 def windowed():
     return _family("mixtral-8x7b")  # reduced window = 16, ring KV cache
+
+
+@pytest.fixture(scope="module")
+def ring_dense():
+    return _family("tinyllama-1.1b", window=16)  # dense, ring KV cache
 
 
 @pytest.fixture(scope="module")
@@ -207,6 +213,75 @@ def test_ssm_chunk_rounded_to_scan_boundary(ssm):
     server = BatchedServer(model, params, slots=2, max_len=MAX_LEN,
                            prefill_chunk=5)
     assert server.prefill_chunk == 2 * cfg.ssm_scan_chunk
+
+
+# ---------------------------------------------------------------------------
+# Fused decode dispatch: inactive lanes, one of them mid chunked prefill
+# ---------------------------------------------------------------------------
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(f"u{a.itemsize}") if a.dtype.kind == "f" or \
+        a.dtype.name == "bfloat16" else a
+
+
+def _select_decode_scan(model, params, cache, tok, active, budget, n):
+    """The fused decode as it froze inactive lanes before: a step over
+    every lane, then a select of the old cache over every leaf."""
+    toks = []
+    for _ in range(n):
+        logits, stepped = model.decode_step(params, cache, tok)
+        nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+        data = jax.tree.map(
+            lambda new, old: jnp.where(
+                active.reshape((1, -1) + (1,) * (new.ndim - 2)), new, old),
+            stepped.data, cache.data)
+        cache = DecodeCache(data, jnp.where(active, stepped.length,
+                                            cache.length))
+        toks.append(jnp.where(active, nxt, 0))
+        tok = jnp.where(active[:, None], nxt[:, None], tok)
+        budget = budget - active.astype(budget.dtype)
+        active = active & (budget > 0)
+    return cache, jnp.stack(toks)
+
+
+@pytest.mark.parametrize("fixture", ["ring_dense", "ssm", "hybrid"])
+def test_dispatch_leaves_inactive_lanes_verbatim(fixture, request):
+    """One ``_dispatch_jit`` over four lanes: two decode (one runs out of
+    budget halfway, one wraps its ring), one is mid chunked prefill and one
+    was never seated.  The inactive lanes' K/V, conv and h bits come out as
+    they went in, and the tokens and the whole cache equal a step-by-step
+    ``decode_step`` frozen by the old whole-cache select."""
+    from repro.serve.engine import _dispatch_jit
+    cfg, model, params = request.getfixturevalue(fixture)
+    cache = model.init_cache(4, MAX_LEN)
+    cache = DecodeCache(cache.data, jnp.zeros(4, jnp.int32))
+    tok = np.zeros((4, 1), np.int32)
+    # lane: (prompt length, tokens prefilled); lane 3 is never seated
+    for lane, (plen, upto) in enumerate([(20, 20), (7, 7), (24, 8)]):
+        prompt = _toks(cfg, (1, plen), seed=lane + 5)
+        for off in range(0, upto, 4):
+            n = min(4, upto - off)
+            last, cache = model.prefill_chunk(
+                params, cache, prompt[:, off:off + n], [off], [n], [lane])
+        tok[lane] = np.argmax(last, -1)
+    tok = jnp.asarray(tok)
+    active = jnp.array([True, True, False, False])
+    budget = jnp.array([6, 2, 0, 0], jnp.int32)
+    before = jax.tree.map(np.asarray, cache)
+    want_cache, want_toks = _select_decode_scan(model, params, cache, tok,
+                                                active, budget, 4)
+    want = jax.tree.map(np.asarray, (want_cache, want_toks))
+    got_cache, _, _, _, toks, emitted = _dispatch_jit(
+        model, 0, 4, (), params, cache, tok, active, budget)
+    assert np.array_equal(np.asarray(toks), want[1])
+    assert np.asarray(emitted)[:, 1].tolist() == [True, True, False, False]
+    for name, leaf in got_cache.data.items():
+        assert np.array_equal(_bits(leaf), _bits(want[0].data[name])), name
+        for lane in (2, 3):  # lane axis 1 in every leaf
+            assert np.array_equal(_bits(leaf[:, lane]),
+                                  _bits(before.data[name][:, lane])), \
+                (name, lane)
+    assert np.asarray(got_cache.length).tolist() == [24, 9, 8, 0]
 
 
 # ---------------------------------------------------------------------------

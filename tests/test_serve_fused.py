@@ -4,7 +4,11 @@ buckets and model families, bucketed prefill must share compiled programs,
 bulk (dispatch-boundary) energy charging must match the seed per-token
 accounting, run() must collect finished work, and chip-aware admission
 must route requests to per-unit fleets."""
+import dataclasses
+import re
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -76,6 +80,49 @@ def test_fused_decode_matches_greedy_other_families(arch):
     server.run(max_steps=100)
     for r, ref in zip(reqs, refs):
         assert r.output == ref, (r.uid, r.output, ref)
+
+
+def _stablehlo_ops(text, op):
+    """(operand dims, result dims) of every ``stablehlo.<op>`` in text."""
+    for line in text.splitlines():
+        if f"stablehlo.{op} " not in line:
+            continue
+        dims = [tuple(int(d) for d in t.split("x")[:-1])
+                for t in re.findall(r"tensor<([^>]*)>", line)]
+        yield dims[:-1], dims[-1]
+
+
+def test_hybrid_decode_scan_copies_nothing():
+    """The lowered decode dispatch of a hybrid with three segments and two
+    shared-block applications reads weights, SSM state and K/V where they
+    lie: no select whose result is a whole K/V, conv or h stack, no
+    concatenate that rebuilds one, and no static slice of a stacked
+    (n_layers, ...) array, weights or state.  The token scan holds the
+    whole program, so the whole program is searched."""
+    from repro.models.model import DecodeCache
+    from repro.serve.engine import _dispatch_jit
+    cfg = dataclasses.replace(get_config("zamba2-1.2b").reduced(),
+                              n_layers=5, shared_attn_every=2)
+    model = LM(cfg)
+
+    def state():
+        cache = model.init_cache(3, 32)
+        return (DecodeCache(cache.data, jnp.zeros(3, jnp.int32)),
+                jnp.zeros((3, 1), jnp.int32), jnp.zeros(3, bool),
+                jnp.zeros(3, jnp.int32))
+
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    cache, tok, active, budget = jax.eval_shape(state)
+    stacks = {a.shape for a in cache.data.values()}
+    assert len(stacks) == 3 and cache.data["k"].shape[0] == 2
+    text = _dispatch_jit.lower(model, 0, 4, (), params, cache, tok, active,
+                               budget).as_text()
+    selects = [r for _, r in _stablehlo_ops(text, "select") if r in stacks]
+    concats = [r for _, r in _stablehlo_ops(text, "concatenate")
+               if r in stacks]
+    slices = [ops[0] for ops, _ in _stablehlo_ops(text, "slice")
+              if ops and ops[0][:1] == (cfg.n_layers,)]
+    assert (selects, concats, slices) == ([], [], [])
 
 
 def test_cache_capped_request_finishes_at_dispatch_boundary(dense):
