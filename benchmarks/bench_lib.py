@@ -1,10 +1,5 @@
-"""Shared benchmark utilities: timing, CSV emission, results trajectories."""
-import json
-import os
+"""Shared benchmark utilities: timing and CSV emission."""
 import time
-
-_RESULTS = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "results")
 
 
 def timed(fn, *args, **kwargs):
@@ -15,18 +10,3 @@ def timed(fn, *args, **kwargs):
 
 def emit(name: str, us: float, derived: str):
     print(f"{name},{us:.1f},{derived}")
-
-
-def append_trajectory(filename: str, record: dict) -> str:
-    """Append one record to a results/<filename> JSON list (the per-PR perf
-    trajectories uploaded as CI artifacts); returns the file path."""
-    os.makedirs(_RESULTS, exist_ok=True)
-    path = os.path.join(_RESULTS, filename)
-    rows = []
-    if os.path.exists(path):
-        with open(path) as f:
-            rows = json.load(f)
-    rows.append(record)
-    with open(path, "w") as f:
-        json.dump(rows, f, indent=1)
-    return path

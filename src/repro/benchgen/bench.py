@@ -7,8 +7,8 @@ so a benchgen prediction plugs into every existing consumer (bottleneck
 classification, roofline fractions, as_dict artifacts).  ``measure`` runs
 the generated kernel; ``validate`` holds the two against each other under a
 multiplicative tolerance and reports the fraction of specs whose measured
-time lands within it — the machine-normalized metric the CI regression
-guard tracks in ``results/benchgen_bench.json``.
+time lands within it — a machine-normalized metric, since prediction and
+measurement share the calibrated clock.
 """
 from __future__ import annotations
 
@@ -76,7 +76,7 @@ def validate(specs: Sequence[KernelSpec],
     Returns ``{"machine": ..., "tol": ..., "rows": [...], "summary": {...}}``
     where each row carries the predicted bound, the measured time, their
     ratio and the within-tolerance verdict; the summary's
-    ``frac_within_tol`` is the guarded trajectory metric.
+    ``frac_within_tol`` is the headline metric.
     """
     if machine is None:
         machine = calibrate()

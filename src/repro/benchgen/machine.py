@@ -5,8 +5,7 @@ the handful of sustained rates that turn a spec's analytic op counts into a
 time.  ``calibrate()`` *measures* them on the current backend with four tiny
 probes (dot / elementwise / round-to-format / exp) plus a streaming copy —
 so predictions and measurements share one clock and the validate() ratio is
-machine-normalized, exactly like the warm-speedup metrics the other bench
-trajectories guard.  ``paper_machine()`` carries the nominal accelerator
+machine-normalized.  ``paper_machine()`` carries the nominal accelerator
 constants of ``repro.launch.mesh`` for offline what-if reports.
 """
 from __future__ import annotations

@@ -15,9 +15,8 @@ Telemetry: pass ``tracer=repro.telemetry.Tracer()`` to ``ClusterRouter``
 ``replay`` (arrival system events); ``trace_cluster`` below wires both and
 returns the tracer (``docs/telemetry.md``).
 """
-from repro.cluster.loadgen import (Arrival, RequestClass, StepCost,
-                                   TraceConfig, generate, latency_stats,
-                                   replay)
+from repro.cluster.loadgen import (Arrival, RequestClass, TraceConfig,
+                                   generate, latency_stats, replay)
 from repro.cluster.router import ClusterRouter, SimClock
 from repro.cluster.spec import ClusterSpec, homogeneous
 from repro.cluster.tune import ChipClass, ClusterTuneResult, tune_cluster
@@ -38,7 +37,7 @@ def trace_cluster(router: ClusterRouter) -> Tracer:
 
 __all__ = [
     "Arrival", "ChipClass", "ClusterRouter", "ClusterSpec",
-    "ClusterTuneResult", "RequestClass", "SimClock", "StepCost",
-    "TraceConfig", "Tracer", "generate", "homogeneous", "latency_stats",
-    "replay", "trace_cluster", "tune_cluster",
+    "ClusterTuneResult", "RequestClass", "SimClock", "TraceConfig",
+    "Tracer", "generate", "homogeneous", "latency_stats", "replay",
+    "trace_cluster", "tune_cluster",
 ]

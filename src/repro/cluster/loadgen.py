@@ -1,4 +1,4 @@
-"""Trace-driven open-loop load generation for the cluster bench.
+"""Trace-driven open-loop load generation for the cluster layer.
 
 Open-loop means arrivals come from a clock, not from the server having
 freed a slot — the only way to see real queueing behavior (p99 latency
@@ -136,40 +136,10 @@ def generate(cfg: TraceConfig, vocab_size: int, *,
     return out
 
 
-@dataclasses.dataclass(frozen=True)
-class StepCost:
-    """Deterministic token-work clock model for ``replay``: after each
-    scheduler step the simulated clock advances by the work the step
-    actually performed (prefill tokens processed x ``t_prefill_token_s``
-    plus decode tokens emitted x ``t_decode_token_s``), read off the
-    target's cumulative counters.  This is what makes admission-latency
-    effects *visible* in simulated time — a monolithic 4k-token prefill
-    step costs 4k prefill-token units while every decode lane waits,
-    whereas a chunked step costs one chunk.  Pure function of the trace
-    and the schedule: no wall-clock noise."""
-
-    t_prefill_token_s: float = 0.0
-    t_decode_token_s: float = 0.0
-
-
-def _work(target) -> Tuple[int, int]:
-    """Cumulative (prefill_tokens, tokens_decoded) across the target's
-    engines (a bare server, or anything exposing ``servers``)."""
-    servers = getattr(target, "servers", None)
-    if servers is None:
-        servers = [target]
-    elif hasattr(servers, "values"):
-        servers = list(servers.values())
-    pf = sum(getattr(s, "prefill_tokens", 0) for s in servers)
-    dec = sum(getattr(s, "tokens_decoded", 0) for s in servers)
-    return pf, dec
-
-
 def replay(target, arrivals: Sequence[Arrival], clock, *,
            tick_s: float, dispatch_tokens: Optional[int] = None,
            max_steps: int = 100_000,
            carryover: Optional[Dict[int, float]] = None,
-           cost: Optional[StepCost] = None,
            tracer=None
            ) -> Dict[str, object]:
     """Open-loop replay of a trace against a server or ``ClusterRouter``.
@@ -187,11 +157,6 @@ def replay(target, arrivals: Sequence[Arrival], clock, *,
     in flight on the target from an earlier replay window (e.g. traffic
     that survived a mid-trace die failure), so their latency is charged
     from their true arrival.
-
-    ``cost`` (a ``StepCost``) additionally advances the clock after each
-    step by that step's measured token work, making scheduling-induced
-    queueing delay observable in simulated time; ``cost=None`` is the
-    plain fixed-tick replay, unchanged.
 
     ``tracer`` (a ``repro.telemetry.Tracer``) records each arrival as a
     system event (class + uid at the arrival instant), so a trace
@@ -221,13 +186,7 @@ def replay(target, arrivals: Sequence[Arrival], clock, *,
             except RequestRejected:
                 rejected.append(pending[i].request)
             i += 1
-        if cost is not None:
-            p0, d0 = _work(target)
         target.step(dispatch_tokens)
-        if cost is not None:
-            p1, d1 = _work(target)
-            clock.t += cost.t_prefill_token_s * (p1 - p0) \
-                + cost.t_decode_token_s * (d1 - d0)
         for uid in [u for u, r in watch.items() if r.output]:
             t0 = submit_t.get(uid)
             if t0 is not None:

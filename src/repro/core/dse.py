@@ -243,7 +243,7 @@ def sweep_loop(designs: Iterable[FPUDesign],
                mix: SpecMix | None = None,
                with_latency: bool = False) -> List[DsePoint]:
     """The original per-point Python loop, kept verbatim as the reference
-    implementation for equivalence tests and benchmarks/dse_bench.py."""
+    implementation for the equivalence tests."""
     params = params or calibrate()
     pts: List[DsePoint] = []
     penalty_cache = {}

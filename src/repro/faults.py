@@ -49,7 +49,7 @@ class UnitFault(RuntimeError):
 # ---------------------------------------------------------------------------
 class FaultKind:
     """Unit-scoped fault taxonomy (string constants, not an enum, so events
-    serialize straight into results/*.json)."""
+    serialize straight into JSON)."""
 
     KILL = "kill"          # unit dies: dispatches on it produce nothing
     THROTTLE = "throttle"  # freq derate by `magnitude` (0<m<1): slower + repriced

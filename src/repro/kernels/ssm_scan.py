@@ -2,18 +2,14 @@
 
 h_t = a_t * h_{t-1} + b_t ;  y_t = <h_t, C_t>
 
-The XLA lowering of this recurrence materializes the (B, S, d_inner, d_state)
-expansion to HBM (~1 MB/token for falcon-mamba-7b — the dominant memory-
-roofline term measured by repro.launch.hillclimb, see
-results/perf_iterations.json).  This kernel keeps the
-expansion in VMEM: each grid step loads a (chunk x d_block) tile of the raw
-per-token inputs (a-decay, b-injection, C-readout), runs the recurrence
-sequentially in registers/VMEM, and writes only y (chunk x d_block) and the
-carried state (d_block x N) back.
-
-HBM traffic per token per layer drops from ~6 * d_inner * N * 4B (three
-(d,N)-expansions round-tripped) to (2N + 2) * d_inner * 4B of interface
-traffic — a ~(3N)x reduction for N=16.
+The kernel takes the (B, S, d_inner, d_state) decay and injection
+expansions ``a`` and ``b`` as inputs from HBM, with the (B, S, d_state)
+readout ``C``.  Each grid step loads a (chunk x d_block) tile of them, runs
+the recurrence sequentially in registers/VMEM, and writes only y
+(chunk x d_block) and the carried state (d_block x N) back, so the state
+sequence ``h`` never reaches HBM.  Building the expansions from dt, x, B, C
+and A inside the kernel, which would also keep ``a`` and ``b`` off HBM, is
+not done here.
 
 Grid: (B, d_inner/bd, S/chunk); the chunk axis is ``arbitrary`` (sequential —
 it carries the state in a VMEM scratch accumulator).  d-tiles are parallel.

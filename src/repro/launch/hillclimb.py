@@ -1,6 +1,5 @@
 """Perf hillclimb driver: hypothesis -> change -> re-lower -> record, for the
-three selected cells (results/perf_iterations.json, rendered into the
-§Perf tables by scripts/make_experiments_md.py).
+three selected cells.
 
 Each iteration re-runs the dry-run cell with a configuration override and
 records the three roofline terms + the fused-kernel memory term.  Results are
@@ -96,6 +95,7 @@ def main():
          "microbatches reduce remat multiplicity"),
         ("falcon-mamba-7b", "train_4k", 1, "mb1", "knee check"),
     ]
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     done = {(r["arch"], r["shape"], r["label"]) for r in rows}
     for arch, shape, mb, label, hyp in plan:
         if (arch, shape, label) in done:

@@ -19,9 +19,8 @@ This module recomputes the memory roofline term under that model:
       ssm scan: per-chunk raw inputs read + y written + carries
   * everything else keeps its parsed HLO traffic.
 
-Reported separately as `t_memory_fused` in results/perf_iterations.json
-(rendered into the perf tables by scripts/make_experiments_md.py); the
-unadjusted XLA number remains the baseline column.
+Reported separately as `t_memory_fused` (``repro.launch.hillclimb`` rows);
+the unadjusted XLA number remains the baseline.
 """
 from __future__ import annotations
 

@@ -51,10 +51,9 @@ step is released without decoding or charging another token; tokens decoded
 in the dispatch during which the deadline passes are kept (the work was
 done).
 
-Greedy sampling only (deterministic; tests compare against per-sample
-decoding bit for bit).  The seed per-token engine is preserved as
-``ReferenceServer`` — the equivalence/energy baseline and the benchmark's
-"before" measurement.
+Greedy sampling only (deterministic).  ``greedy_decode`` below is the
+reference: a single-sequence decoder that the tests compare every serving
+path against bit for bit.
 """
 from __future__ import annotations
 
@@ -1303,149 +1302,6 @@ class BatchedServer:
             if self.idle():
                 break
             self.step(n)
-        out, self.finished = self.finished, []
-        return out
-
-
-# ---------------------------------------------------------------------------
-# The seed per-token engine, frozen as the equivalence / benchmark baseline
-# ---------------------------------------------------------------------------
-class ReferenceServer:
-    """The pre-overhaul engine: one host sync and one ``ChipPolicy`` charge
-    per decoded token, single-prompt eager prefill, full cache rebuild per
-    admission.  Kept as the bitwise/energy baseline the fused engine is
-    tested against and the ``serve_bench`` "before" measurement (only the
-    seed's always-empty ``run()`` return is fixed here too).
-    """
-
-    def __init__(self, model: LM, params, *, slots: int, max_len: int,
-                 pad_id: int = 0, chip_policy=None,
-                 flops_per_token: Optional[float] = None):
-        self.model = model
-        self.params = params
-        self.slots = slots
-        self.max_len = max_len
-        self.pad_id = pad_id
-        self.cfg = model.cfg
-        self.chip_policy = chip_policy
-        self._precision = getattr(self.cfg, "numerics_precision", None)
-        if flops_per_token is None and hasattr(self.cfg,
-                                               "active_param_count"):
-            flops_per_token = 2.0 * self.cfg.active_param_count()
-        self.flops_per_token = flops_per_token or 0.0
-        self.tokens_decoded = 0
-        self._unit_energy_j: Dict[str, float] = {}
-        self._queue: List[Request] = []
-        self._active: List[Optional[Request]] = [None] * slots
-        self.finished: List[Request] = []
-        # per-slot caches are merged into one batched cache
-        self.cache = model.init_cache(slots, max_len)
-        self._slot_len = np.zeros(slots, np.int32)
-        self._next_tok = np.full((slots, 1), pad_id, np.int32)
-        self._decode = jax.jit(
-            lambda p, c, t: model.decode_step(p, c, t))
-
-    def _charge(self, req: Request, phase: str, flops: float) -> None:
-        """Account ``flops`` on the unit the chip routes ``phase`` to."""
-        if self.chip_policy is None or not flops:
-            return
-        unit = self.chip_policy.unit_for_phase(phase,
-                                               precision=self._precision)
-        e_j = self.chip_policy.request_energy_j(phase, flops,
-                                                precision=self._precision)
-        req.energy_j += e_j
-        req.unit_energy_j[unit.name] = \
-            req.unit_energy_j.get(unit.name, 0.0) + e_j
-        self._unit_energy_j[unit.name] = \
-            self._unit_energy_j.get(unit.name, 0.0) + e_j
-
-    def energy_report(self) -> Dict[str, object]:
-        total = sum(self._unit_energy_j.values())
-        return dict(
-            chip=self.chip_policy.spec.name if self.chip_policy else None,
-            total_j=total,
-            per_unit_j=dict(self._unit_energy_j),
-            tokens_decoded=self.tokens_decoded,
-            j_per_token=(total / self.tokens_decoded
-                         if self.tokens_decoded else 0.0))
-
-    def submit(self, req: Request):
-        self._queue.append(req)
-
-    def _admit(self):
-        for slot in range(self.slots):
-            if self._active[slot] is None and self._queue:
-                req = self._queue.pop(0)
-                self._active[slot] = req
-                if self.chip_policy is not None:
-                    req.routed_unit = self.chip_policy.unit_for_phase(
-                        "decode", precision=self._precision).name
-                last, cache1 = self.model.prefill(
-                    self.params, jnp.asarray(req.prompt[None]),
-                    max_len=self.max_len)
-                self._charge(req, "prefill",
-                             self.flops_per_token * len(req.prompt))
-                self._write_slot_cache(slot, cache1)
-                self._slot_len[slot] = len(req.prompt)
-                tok = int(jnp.argmax(last, -1)[0])
-                req.output.append(tok)
-                self.tokens_decoded += 1
-                self._next_tok[slot, 0] = tok
-                if len(req.output) >= req.max_new_tokens:
-                    req.done = True
-                    self.finished.append(req)
-                    self._active[slot] = None
-
-    def _write_slot_cache(self, slot, cache1):
-        # cache data leaves are (L, B, ...) — batch is axis 1
-        new_data = {}
-        for k, dst in self.cache.data.items():
-            src = cache1.data[k]
-            pad = [(0, 0)] * src.ndim
-            if k in ("k", "v") and src.shape[2] != dst.shape[2]:
-                pad[2] = (0, dst.shape[2] - src.shape[2])
-                src = jnp.pad(src, pad)
-            new_data[k] = dst.at[:, slot].set(src[:, 0])
-        self.cache = type(self.cache)(new_data, self.cache.length)
-
-    def step(self) -> int:
-        """One decode step over all active slots. Returns #active."""
-        self._admit()
-        active = [s for s, r in enumerate(self._active) if r is not None]
-        if not active:
-            return 0
-        cache = self.model.cache_at_length(
-            self.cache, jnp.asarray(self._slot_len, jnp.int32))
-        logits, cache = self._decode(self.params, cache,
-                                     jnp.asarray(self._next_tok))
-        self.cache = cache
-        toks = np.asarray(jnp.argmax(logits[:, -1], -1))
-        now = time.monotonic()
-        for slot in active:
-            req = self._active[slot]
-            self._slot_len[slot] += 1
-            tok = int(toks[slot])
-            req.output.append(tok)
-            self.tokens_decoded += 1
-            self._charge(req, "decode", self.flops_per_token)
-            self._next_tok[slot, 0] = tok
-            if req.deadline_s is not None and now > req.deadline_s:
-                req.expired = True
-                req.done = True
-            if len(req.output) >= req.max_new_tokens:
-                req.done = True
-            if req.done:
-                self.finished.append(req)
-                self._active[slot] = None
-        return len(active)
-
-    def run(self, max_steps: int = 10_000) -> List[Request]:
-        """Serve until drained; returns the requests finished since the
-        last ``run`` call (the seed returned an always-empty list)."""
-        for _ in range(max_steps):
-            if not self._queue and all(r is None for r in self._active):
-                break
-            self.step()
         out, self.finished = self.finished, []
         return out
 

@@ -45,9 +45,8 @@ engine:
 
 Recovery latency (fault detection -> every affected request re-seated on a
 serving fleet), requeues, sheds, and wasted energy are all surfaced via
-``resilience_report()``; ``benchmarks/resilience_bench.py`` drives seeded
-kill/throttle/corrupt/flap scenarios through this layer and records them
-in ``results/resilience_bench.json``.
+``resilience_report()``; ``tests/test_resilience.py`` drives seeded
+kill/throttle/corrupt/flap scenarios through this layer.
 """
 from __future__ import annotations
 

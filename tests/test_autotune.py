@@ -14,7 +14,7 @@ from repro.core.energy_model import SweepExecutableCache, calibrate, predict
 from repro.core.fpu_arch import FABRICATED
 from repro.core.trace import OpProfile
 
-# Small electrical grids keep unit-test sweeps fast; the benchmark exercises
+# Small electrical grids keep unit-test sweeps fast; the library defaults are
 # the full TUNE_* grids.
 VDD = np.round(np.arange(0.55, 1.101, 0.05), 3)
 VBB = np.round(np.arange(0.0, 1.21, 0.3), 2)

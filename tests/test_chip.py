@@ -17,7 +17,7 @@ from repro.core.formats import BF16
 from repro.core.fpu_arch import FABRICATED
 
 # Small electrical grids keep unit-test sweeps fast (same grids as
-# tests/test_autotune.py); benchmarks exercise the full TUNE_* grids.
+# tests/test_autotune.py); the library defaults are the full TUNE_* grids.
 VDD = np.round(np.arange(0.55, 1.101, 0.05), 3)
 VBB = np.round(np.arange(0.0, 1.21, 0.3), 2)
 
@@ -224,7 +224,7 @@ def test_budgets_size_the_fleet_and_validate(params, cache, two_phase):
     # the throughput phase carries 70% of the FLOPs -> more instances
     assert r.spec.units[0].count > r.spec.units[1].count
     assert r.spec.gflops_per_w > 0
-    # report is json-serializable (chip_bench commits it)
+    # report is json-serializable
     json.dumps(r.report)
 
 

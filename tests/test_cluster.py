@@ -27,7 +27,7 @@ from repro.serve.engine import (BatchedServer, Request, RequestRejected,
 from helpers import make_chip_unit as unit
 
 # Small electrical grids keep the tune_cluster sweeps fast (same grids as
-# tests/test_chip.py); benchmarks exercise the full TUNE_* grids.
+# tests/test_chip.py); the library defaults are the full TUNE_* grids.
 VDD = np.round(np.arange(0.55, 1.101, 0.05), 3)
 VBB = np.round(np.arange(0.0, 1.21, 0.3), 2)
 TICK = 0.05
@@ -378,11 +378,16 @@ def test_one_die_cluster_matches_batched_server_bitwise(dense):
 
 
 # ----------------------------------------------------- trace -> cluster
-def test_trace_replay_over_heterogeneous_dies(dense):
-    """A small seeded bursty trace end-to-end through the router: every
-    arrival finishes, latencies are positive, stats are consistent."""
+@pytest.mark.parametrize("prefill_chunk", [None, 4],
+                         ids=["monolithic", "chunked"])
+def test_trace_replay_over_heterogeneous_dies(dense, prefill_chunk):
+    """A small seeded bursty trace end-to-end through the router, with
+    monolithic or cluster-wide chunked admission: every arrival finishes
+    with the ``greedy_decode`` output, latencies are positive, stats are
+    consistent."""
     cfg = dense[0]
-    router, clock = _router(dense, _eco_gold_cluster())
+    router, clock = _router(dense, _eco_gold_cluster(),
+                            prefill_chunk=prefill_chunk)
     trace = generate(
         TraceConfig(horizon_s=4.0, base_rate_rps=1.5, seed=9,
                     classes=(RequestClass("loose", weight=3,
@@ -395,6 +400,9 @@ def test_trace_replay_over_heterogeneous_dies(dense):
     rep = replay(router, trace, clock, tick_s=TICK, dispatch_tokens=3)
     assert len(rep["finished"]) == len(trace)
     assert not rep["rejected"] and not rep["expired"]
+    refs = _refs(dense, [a.request for a in trace])
+    for r in rep["finished"]:
+        assert r.output == refs[r.uid], r.uid
     st = latency_stats(rep["latency_s"])
     assert st["n"] == len(trace)
     assert 0.0 < st["p50_s"] <= st["p99_s"] <= st["max_s"]

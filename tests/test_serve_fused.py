@@ -16,8 +16,8 @@ from repro.configs.base import get_config
 from repro.core import chip
 from repro.core.energy_model import calibrate
 from repro.models import LM
-from repro.serve.engine import (BatchedServer, ReferenceServer, Request,
-                                bucket_length, greedy_decode)
+from repro.serve.engine import (BatchedServer, Request, bucket_length,
+                                greedy_decode)
 
 from helpers import FakeClock
 
@@ -210,15 +210,19 @@ def test_slot_churn_under_mixed_deadlines(dense):
 # ------------------------------------------------------------------ energy
 def test_bulk_energy_matches_per_token_reference(dense):
     """Dispatch-boundary (device-counted) charging == the seed's per-token
-    charging, per request and per unit, with identical outputs."""
+    charging in closed form: the prompt's FLOPs on the prefill unit, one
+    token's FLOPs per decoded token after the first on the decode unit,
+    per request and per unit, with outputs equal to ``greedy_decode``."""
     cfg, model, params = dense
     tech = calibrate()
     prompts = _prompts(cfg, (4, 9, 6, 12))
+    fpt = 2.0 * cfg.active_param_count()
+    prec = cfg.numerics_precision
 
-    def serve(cls, **kw):
+    def serve(**kw):
         policy = chip.ChipPolicy(chip.fabricated_chip("sp", tech), tech)
-        server = cls(model, params, slots=2, max_len=32, chip_policy=policy,
-                     **kw)
+        server = BatchedServer(model, params, slots=2, max_len=32,
+                               chip_policy=policy, **kw)
         reqs = [Request(uid=i, prompt=p, max_new_tokens=6)
                 for i, p in enumerate(prompts)]
         for r in reqs:
@@ -228,24 +232,29 @@ def test_bulk_energy_matches_per_token_reference(dense):
                 break
         return server, reqs
 
-    ref_server, ref_reqs = serve(ReferenceServer)
-    new_server, new_reqs = serve(BatchedServer)
+    new_server, new_reqs = serve()
     # drain multi-token dispatches too: same totals at coarser granularity
-    bulk_server, bulk_reqs = serve(BatchedServer, dispatch_tokens=4)
+    bulk_server, bulk_reqs = serve(dispatch_tokens=4)
     bulk_server.run(max_steps=10)
-    for ref, a, b in zip(ref_reqs, new_reqs, bulk_reqs):
-        assert a.output == ref.output == b.output
-        assert a.energy_j == pytest.approx(ref.energy_j, rel=1e-9)
-        assert b.energy_j == pytest.approx(ref.energy_j, rel=1e-9)
-        for unit, e in ref.unit_energy_j.items():
-            assert a.unit_energy_j[unit] == pytest.approx(e, rel=1e-9)
-            assert b.unit_energy_j[unit] == pytest.approx(e, rel=1e-9)
-    ref_rep = ref_server.energy_report()
+    policy = new_server.chip_policy
+    want_units = {}
+    for i, p in enumerate(prompts):
+        ref = greedy_decode(model, params, p, 6, max_len=32)
+        assert new_reqs[i].output == ref == bulk_reqs[i].output
+        split = {}
+        for ph, flops in (("prefill", fpt * len(p)),
+                          ("decode", fpt * (len(ref) - 1))):
+            unit = policy.unit_for_phase(ph, precision=prec).name
+            e = policy.request_energy_j(ph, flops, precision=prec)
+            split[unit] = split.get(unit, 0.0) + e
+            want_units[unit] = want_units.get(unit, 0.0) + e
+        for r in (new_reqs[i], bulk_reqs[i]):
+            assert r.energy_j == pytest.approx(sum(split.values()), rel=1e-9)
+            assert r.unit_energy_j == pytest.approx(split, rel=1e-9)
     for server in (new_server, bulk_server):
         rep = server.energy_report()
-        assert rep["tokens_decoded"] == ref_rep["tokens_decoded"]
-        for unit, e in ref_rep["per_unit_j"].items():
-            assert rep["per_unit_j"][unit] == pytest.approx(e, rel=1e-9)
+        assert rep["tokens_decoded"] == sum(len(r.output) for r in new_reqs)
+        assert rep["per_unit_j"] == pytest.approx(want_units, rel=1e-9)
 
 
 # ----------------------------------------------------------- fleet routing

@@ -45,6 +45,9 @@ from helpers import FakeClock, make_chip_unit as unit
 
 TICK = 0.05
 MAX_LEN = 64
+# small electrical grids keep the trace-driven tune_chip fast
+TUNE_VDD = np.round(np.arange(0.55, 1.101, 0.05), 3)
+TUNE_VBB = np.round(np.arange(0.0, 1.21, 0.3), 2)
 
 
 @pytest.fixture(scope="module")
@@ -468,7 +471,11 @@ def test_die_kill_mid_prefill_keeps_one_causal_tree_per_request(dense):
 
 
 # ------------------------------------------- trace-derived profiles
-def test_profile_from_trace_uses_measured_activity(dense):
+@pytest.mark.parametrize("tune", [False, True], ids=["profile", "tune_chip"])
+def test_profile_from_trace_uses_measured_activity(dense, tune):
+    """The trace yields measured activities; with ``tune``, ``tune_chip``
+    over the trace's phases builds one unit per phase at that phase's
+    measured activity (the trace -> profile -> chip loop closed)."""
     cfg, model, params = dense
     clock = FakeClock()
     tracer = Tracer()
@@ -503,6 +510,14 @@ def test_profile_from_trace_uses_measured_activity(dense):
     assert sum(p.flops_fraction for p in phases) == pytest.approx(1.0)
     for p in phases:
         assert p.profile.activity >= MIN_ACTIVITY
+    if not tune:
+        return
+    tuned = chip.tune_chip(phases, params=calibrate(), vdd_grid=TUNE_VDD,
+                           vbb_grid=TUNE_VBB, name="trace_die")
+    rows = tuned.report["units"]
+    assert [r["unit"] for r in rows] == [p.name for p in phases]
+    for row, p in zip(rows, phases):
+        assert row["activity"] == pytest.approx(p.profile.activity)
 
 
 def test_profile_from_trace_round_trips_through_jsonl(dense, tmp_path):
